@@ -19,6 +19,7 @@ from benchmarks import (engine_speedup, fig3_sensitivity, fig6_hparams,
                         serve_throughput, sharded_speedup,
                         table1_complexity, table2_quality, table3_scale,
                         table4_edm, table5_orthogonality, table6_bias)
+from repro.utils import enable_compile_cache
 
 TABLES = {
     "table1_complexity": table1_complexity,
@@ -61,6 +62,7 @@ def main() -> None:
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None, choices=list(TABLES) + [None])
     args = ap.parse_args()
+    enable_compile_cache()
 
     failures = []
     for name, mod in TABLES.items():

@@ -1,9 +1,11 @@
 """Sharded GoldDiffEngine vs single host on an emulated 8-device mesh.
 
-Wall-clock on an *emulated* mesh is not a speedup claim — the eight XLA
-"devices" share one physical CPU and every collective is a memcpy — so
-the timing cells here are recorded **unpaired** (a trajectory to watch,
-not a gate; real-hardware scaling is the ROADMAP follow-on).  What IS
+A CPU-only parity check: it re-executes itself pinned to the CPU and is
+never a chip number (the chip's sharded path is ``chip_smoke.py
+--chips 4``, in one process).  Wall-clock on an *emulated* mesh is not a
+speedup claim — the eight XLA "devices" share one physical CPU and
+every collective is a memcpy — so the timing cells here are recorded
+**unpaired** (a trajectory to watch, not a gate).  What IS
 gated (``scripts/check_bench.py``, >= 0.95 like every recall cell) is
 **parity**: the sharded engine must keep producing the single-host
 golden sets and denoised outputs —

@@ -46,7 +46,8 @@ class OptimalDenoiser:
     (full scans via the streaming-softmax ``golden_aggregate`` kernel,
     supports via matmul-form ``support_distances`` +
     ``golden_support_aggregate``); ``backend`` selects
-    xla / pallas_interpret / pallas uniformly.  The biased ``wss``
+    xla / pallas_interpret / pallas uniformly, and ``None`` takes the
+    platform's (``ops.platform_backend``).  The biased ``wss``
     weighting keeps the chunked streaming estimators (the bias model of
     Sec. 3.2 is chunk-structured by definition).
     """
@@ -55,12 +56,12 @@ class OptimalDenoiser:
 
     def __init__(self, store: DatasetStore, schedule: Schedule,
                  chunk: int = 8192, weighting: Weighting = "ss",
-                 backend: str = "xla"):
+                 backend: str | None = None):
         self.store = store
         self.schedule = schedule
         self.chunk = chunk
         self.weighting = weighting
-        self.backend = backend
+        self.backend = ops.platform_backend() if backend is None else backend
 
     def logits(self, x_t: Array, t: int) -> Array:
         """Full-scan logits l_i = -||x_t/a_t - x_i||^2 / (2 sigma_t^2); [B,N]."""

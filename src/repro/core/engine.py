@@ -33,10 +33,11 @@ Engine features:
   keeps the dataset (and proxy) operands in bf16 for bandwidth while
   row norms stay fp32 (computed from the fp32 master copy) and every
   distance/softmax/accumulation runs in fp32.
-* **uniform backends** — ``xla`` (CPU tests, benchmarks, the multi-pod
-  dry-run), ``pallas_interpret`` (kernel-body validation on CPU), and
-  ``pallas`` (real TPUs) all execute the same pipeline; parity is
-  asserted in ``tests/test_engine.py``.
+* **uniform backends** — ``xla`` (the reference math; the default off
+  TPU), ``pallas_interpret`` (kernel-body validation on CPU, only when
+  named), and ``pallas`` (the default on TPU) all execute the same
+  pipeline; ``backend=None`` takes ``ops.platform_backend()``.  Parity
+  is asserted in ``tests/test_engine.py``.
 
 Backend/strategy matrix::
 
@@ -46,6 +47,10 @@ Backend/strategy matrix::
     xla + gather      row gather + einsum     row gather + einsum
     pallas_interpret  gather + tiled kernel   gather + streaming kernel
     pallas            gather + tiled kernel   gather + streaming kernel
+
+On the pallas backends the streamed screen and the fused candidate pass
+run as ``lax.scan`` programs (Mosaic cannot lower their top-m merge);
+every other stage is a compiled Pallas kernel.
 
 The xla *strategy* (gather vs dense) is selected per platform at engine
 build time: XLA:CPU row gathers run ~50x slower per element than GEMM,
@@ -136,8 +141,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import math
+import os
 import threading
 import time
+from concurrent.futures import Future, ThreadPoolExecutor
 from typing import NamedTuple
 
 import jax
@@ -280,13 +287,16 @@ class GoldDiffEngine:
     """Compiled-program cache + kernel routing for the GoldDiff pipeline."""
 
     def __init__(self, store: DatasetStore, schedule: Schedule,
-                 cfg: GoldDiffConfig | None = None, backend: str = "xla",
+                 cfg: GoldDiffConfig | None = None,
+                 backend: str | None = None,
                  storage_dtype=None, index: GoldenIndex | None = None,
                  probe_schedule: ProbeSchedule | None = None,
                  strategy: str = "auto", index_mode: str = "auto",
                  mesh=None, shard_axis: str = "data",
                  screen: str = "auto", screen_tile: int | None = None,
                  fused: str | bool = "auto", batch_axis: str | None = None):
+        if backend is None:
+            backend = ops.platform_backend()
         if backend not in ops.BACKENDS:
             raise ValueError(f"unknown backend {backend!r}; "
                              f"expected one of {ops.BACKENDS}")
@@ -384,6 +394,9 @@ class GoldDiffEngine:
         # segment dispatch to detect post-warmup compiles (a cache-size
         # delta misses evict-then-rebuild recompile storms)
         self._builds = 0
+        # AOT compiles in flight inside ``parallel_compiles``
+        self._compile_pool: ThreadPoolExecutor | None = None
+        self._compile_futures: list[Future] = []
 
     # -- epoch-swappable store operands ---------------------------------------
     def _make_operands(self, store: DatasetStore,
@@ -551,6 +564,28 @@ class GoldDiffEngine:
         return tuple(None if a is None else (tuple(a.shape), str(a.dtype))
                      for a in ops_)
 
+    def _bind(self, fn):
+        """``fn`` with the store operands as a leading argument: inside
+        the call the operand properties resolve to that argument."""
+        def traced(ops_, *args):
+            self._tls.bound = ops_
+            try:
+                return fn(*args)
+            finally:
+                self._tls.bound = None
+        return traced
+
+    def lower(self, fn, *specs):
+        """``fn`` lowered for ``specs`` with the store operands threaded
+        as arguments, exactly as :meth:`jitter` compiles it — for
+        reading a program's text (which kernels it calls) without
+        compiling or running it.  Single-host engines only."""
+        if self.mesh is not None:
+            raise ValueError("lower() reads single-host programs; a "
+                             "sharded program bakes its mesh layout")
+        return jax.jit(self._bind(fn)).lower(self.current_operands(),
+                                             *specs)
+
     def jitter(self, fn, aot_specs: tuple | None = None):
         """Epoch-aware ``jax.jit``: compile ``fn`` with the store
         operands threaded as real arguments, not baked constants.
@@ -564,28 +599,31 @@ class GoldDiffEngine:
         pipeline-stage methods need no signature changes.
 
         ``aot_specs`` (a tuple of ``ShapeDtypeStruct``) AOT-lowers for
-        those input avals immediately — the serving warmup path.  AOT
-        executables are cached per operand-shape signature; an epoch
-        whose shapes were never lowered falls back to a fresh compile,
-        counted in ``_builds`` so the post-warmup recompile guard stays
-        honest.  Sharded engines return plain ``jax.jit`` (their
-        operands live in the mesh layout; they do not hot-swap).
+        those input avals immediately — the serving warmup path (inside
+        :meth:`parallel_compiles` the compile runs on its thread pool
+        and the first call waits for it).  AOT executables are cached
+        per operand-shape signature; an epoch whose shapes were never
+        lowered falls back to a fresh compile, counted in ``_builds`` so
+        the post-warmup recompile guard stays honest.  Sharded engines
+        return plain ``jax.jit`` (their operands live in the mesh
+        layout; they do not hot-swap).
         """
         if self.mesh is not None:
             return jax.jit(fn)
-
-        def traced(ops_, *args):
-            self._tls.bound = ops_
-            try:
-                return fn(*args)
-            finally:
-                self._tls.bound = None
-
-        jf = jax.jit(traced)
+        jf = jax.jit(self._bind(fn))
         if aot_specs is None:
             return lambda *args: jf(self.current_operands(), *args)
+
+        def compile_for(ops_):
+            return jf.lower(ops_, *aot_specs).compile()
+
         ops0 = self.current_operands()
-        execs = {self._ops_sig(ops0): jf.lower(ops0, *aot_specs).compile()}
+        if self._compile_pool is None:
+            first = compile_for(ops0)
+        else:
+            first = self._compile_pool.submit(compile_for, ops0)
+            self._compile_futures.append(first)
+        execs = {self._ops_sig(ops0): first}
 
         def call(*args):
             ops_ = self.current_operands()
@@ -593,11 +631,39 @@ class GoldDiffEngine:
             compiled = execs.get(sig)
             if compiled is None:         # changed-shape epoch: honest
                 self._builds += 1        # post-warmup compile accounting
-                compiled = jf.lower(ops_, *aot_specs).compile()
+                compiled = compile_for(ops_)
                 execs[sig] = compiled
+            elif isinstance(compiled, Future):
+                compiled = execs[sig] = compiled.result()
             return compiled(ops_, *args)
 
         return call
+
+    @contextlib.contextmanager
+    def parallel_compiles(self):
+        """Run the AOT compiles started inside this block on a thread
+        pool (half the host's cores, at most 8); leave it only when all
+        have finished (re-raising the first failure).
+
+        XLA compiles outside the GIL, and a serving warmup is a few
+        dozen independent programs — on a TPU each takes ~20 s, most of
+        it in the large-k ``top_k`` of the screen and re-rank — so
+        compiling them side by side is what bounds cold start.  Nested
+        blocks share the outer pool.
+        """
+        if self._compile_pool is not None:
+            yield
+            return
+        self._compile_pool = ThreadPoolExecutor(
+            max(1, min(8, (os.cpu_count() or 2) // 2)))
+        try:
+            yield
+            for f in self._compile_futures:
+                f.result()
+        finally:
+            self._compile_pool.shutdown(wait=True)
+            self._compile_pool = None
+            self._compile_futures = []
 
     # -- precomputed per-timestep constants ----------------------------------
     def sizes(self, t: int) -> tuple[int, int]:

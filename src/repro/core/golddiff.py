@@ -60,7 +60,7 @@ __all__ = ["GoldDiff", "GoldDiffConfig", "GoldDiffEngine", "schedule_sizes",
 
 
 def coarse_screen(store: DatasetStore, q: Array, m: int, proxy_factor: int,
-                  backend: str = "xla") -> Array:
+                  backend: str | None = None) -> Array:
     """Top-m candidate indices by proxy distance.  q: [B, D] -> [B, m].
 
     Routed through ``ops.pdist`` (tiled matmul form, precomputed norms).
@@ -73,7 +73,7 @@ def coarse_screen(store: DatasetStore, q: Array, m: int, proxy_factor: int,
 
 
 def golden_select(store: DatasetStore, q: Array, cand: Array, k: int,
-                  backend: str = "xla") -> Array:
+                  backend: str | None = None) -> Array:
     """Exact re-ranking inside the candidate set (Eq. 5). Returns [B, k].
 
     Matmul-form distances via ``ops.golden_rerank`` — no [B, m, D]
@@ -90,8 +90,9 @@ class GoldDiff:
     ``backend`` / ``storage_dtype`` / ``strategy`` configure the
     execution engine (see :class:`GoldDiffEngine`); ``backend=None``
     (default) inherits the base denoiser's backend so the fused path and
-    the explicit ``support=`` path run the same kernels.  ``xla`` is the
-    fast path on CPU, ``pallas`` lowers the TPU kernels.  Pass
+    the explicit ``support=`` path run the same kernels, and a base
+    without one takes the platform's (``ops.platform_backend``:
+    ``pallas`` on TPU, ``xla`` elsewhere).  Pass
     ``index=repro.index.build_index(store)`` to route coarse screening
     through the clustered Golden Index (sublinear in N; probe width set
     by ``probe_schedule``).  Pass ``mesh=``/``shard_axis=`` to
@@ -125,7 +126,7 @@ class GoldDiff:
         self.name = f"golddiff+{base.name}"
         self.jit_steps = jit_steps
         if backend is None:
-            backend = getattr(base, "backend", "xla")
+            backend = getattr(base, "backend", None)
         engine_kw = {} if screen_tile is None else \
             {"screen_tile": screen_tile}
         self.engine = GoldDiffEngine(self.store, self.schedule, self.cfg,

@@ -27,10 +27,10 @@ implementation of the two-stage top-k + LSE merge in the repo
 in ``tests/test_sharded_engine.py``.
 
 The shard-local distance math goes through the kernel ops layer
-(``repro.kernels.ops``, ``backend="xla"``: shard_map bodies compile for
-whatever mesh platform is active, where Pallas TPU kernels may not
-lower), so the matmul-form distances here are the exact same code the
-single-host GoldDiffEngine runs.
+(``repro.kernels.ops``; ``backend=None`` takes the platform's — the
+compiled kernels on a TPU mesh, the reference math elsewhere), so the
+matmul-form distances here are the exact same code the single-host
+GoldDiffEngine runs.
 """
 from __future__ import annotations
 
@@ -94,7 +94,7 @@ def build_shard_indexes(store: DatasetStore, mesh: Mesh, axis: str = "data",
 # -- shard-local pipeline stages (shard_map bodies; engine-callable) ---------
 
 def local_coarse_exact(qp, proxy_loc, pnorms_loc, m_cap: int, m_sort: int,
-                       m, axis: str, backend: str = "xla",
+                       m, axis: str, backend: str | None = None,
                        stream: bool = False, tile: int | None = None):
     """Shard-local exact proxy screening + cross-shard top-m threshold.
 
@@ -118,7 +118,7 @@ def local_coarse_exact(qp, proxy_loc, pnorms_loc, m_cap: int, m_sort: int,
 
 
 def golden_local_topk(X_loc, xn_loc, q, cand, cand_valid, k_cap: int,
-                      k_sort: int, k, axis: str, backend: str = "xla",
+                      k_sort: int, k, axis: str, backend: str | None = None,
                       strategy: str = "gather"):
     """Exact shard-local re-rank + stage-two global top-k threshold.
 
@@ -147,7 +147,7 @@ def merged_golden_mean(X_loc, idx, neg, kth, sig2, axis: str,
 
 def fused_local_step(X_loc, xn_loc, q, qp, proxy_loc, pnorms_loc,
                      m_cap: int, m_sort: int, m, k_cap: int, k_sort: int, k,
-                     sig2, axis: str, backend: str = "xla",
+                     sig2, axis: str, backend: str | None = None,
                      strategy: str = "gather", stream: bool = False,
                      tile: int | None = None) -> Array:
     """One fused shard-local GoldDiff step with collective-compute overlap.
@@ -226,7 +226,7 @@ def distributed_golden_denoise(store: DatasetStore, mesh: Mesh, q: Array,
             qp = downsample_proxy(q_img, proxy_factor)
             cand, pd2 = ops.ivf_screen_local(
                 qp, offs, cents, cnorms, wr[0], wr[1], nprobe,
-                index.max_cluster, w_cap, index.n_loc, backend="xla")
+                index.max_cluster, w_cap, index.n_loc)
             idx, neg, kth = golden_local_topk(X, xn, q_rep, cand,
                                               jnp.isfinite(pd2), k_cap,
                                               k, k, axis)

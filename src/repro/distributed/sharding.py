@@ -182,13 +182,10 @@ def mesh_axis_size(*names: str) -> int:
 # -- cross-shard merge primitives (shard_map bodies only) --------------------
 
 def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` (jax >= 0.6) or the experimental fallback,
-    with replication checking off (outputs are psum/pmax-replicated)."""
-    kw = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, check_vma=False, **kw)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, check_rep=False, **kw)
+    """``jax.shard_map`` with replication checking off (outputs are
+    psum/pmax-replicated)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def crossshard_kth(neg_local: jnp.ndarray, k_sort: int, k,

@@ -22,15 +22,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import dot_f32
+
 DEFAULT_BQ = 8
 DEFAULT_BC = 128
 
 
 def _centroid_kernel(q_ref, c_ref, qn_ref, cn_ref, out_ref):
-    q = q_ref[...]
-    c = c_ref[...]
-    acc = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    acc = dot_f32(q_ref[...], c_ref[...], ((1,), (1,)))
     d2 = qn_ref[...] + cn_ref[...] - 2.0 * acc
     out_ref[...] = jnp.maximum(d2, 0.0)
 
@@ -39,10 +38,10 @@ def _centroid_kernel(q_ref, c_ref, qn_ref, cn_ref, out_ref):
 def centroid_scan(q: jnp.ndarray, centroids: jnp.ndarray,
                   c_norms: jnp.ndarray | None = None,
                   bq: int = DEFAULT_BQ, bc: int = DEFAULT_BC,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool = False) -> jnp.ndarray:
     """||q_i - c_j||^2 for q: [B, d], centroids: [C, d] -> [B, C] fp32.
 
-    interpret=True on CPU (validation); False lowers for real TPUs.
+    ``interpret=True`` runs the kernel body on the CPU (validation only).
     """
     b, d = q.shape
     c = centroids.shape[0]

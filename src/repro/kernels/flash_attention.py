@@ -69,7 +69,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, m_ref, l_ref, acc_ref,
 @functools.partial(jax.jit, static_argnames=("causal", "qc", "kc", "interpret"))
 def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = True, qc: int = DEFAULT_QC,
-                    kc: int = DEFAULT_KC, interpret: bool = True) -> jnp.ndarray:
+                    kc: int = DEFAULT_KC,
+                    interpret: bool = False) -> jnp.ndarray:
     """q: [B, Hkv, G, S, dh]; k/v: [B, Hkv, S, dh] -> [B, Hkv, G, S, dh]."""
     b, hkv, g, s, dh = q.shape
     qc = min(qc, s)

@@ -26,17 +26,14 @@ contraction is unaffected by N tiling), so fused-vs-staged agree to
 fp32 *reduction order* (the aggregation sums in gathered instead of
 scattered order), ~1e-7.
 
-Two implementations share the math, mirroring ``kernels.screen``:
-
-* ``fused_candidates_pallas`` — Pallas megakernel: one grid pass with
-  (values, indices, exact-d2) VMEM scratch carried across the N axis,
-  two MXU matmuls (proxy + exact) and one merge per tile.
-* ``fused_candidates_scan``   — ``lax.scan`` twin for any XLA backend
-  (ragged tails overlap back, re-seen columns masked; no padded copy).
-
-``fused_posterior`` is the shared epilogue; ``ops.fused_step`` is the
-dispatching entry point (it also provides the materialized form used
-below the streamed-screen byte crossover).
+``fused_candidates_scan`` runs the pass as a ``lax.scan`` over store
+tiles (ragged tails overlap back, re-seen columns masked; no padded
+copy) on every backend, the TPU included: like ``kernels.screen``, the
+merge needs ``top_k``, which Mosaic cannot lower inside a Pallas
+kernel.  ``fused_posterior`` is the epilogue — on the pallas backends
+its aggregate is the ``golden_support_aggregate`` kernel;
+``ops.fused_step`` is the dispatching entry point (it also provides the
+materialized form used below the streamed-screen byte crossover).
 
 Slot semantics (shared with ``ops.screen_topm``): ``m > N`` surplus
 slots carry exact ``d2 = +inf`` and a clamped in-range index, so they
@@ -50,16 +47,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
+from repro.kernels.common import dot_f32
 from repro.kernels.golden_support_aggregate import (
     golden_support_aggregate as _sagg)
 
 NEG_INF = -1e30
-DEFAULT_BQ = 8
-DEFAULT_TILE = 4096
 # Scan-path default tile for the FUSED pass.  Unlike the proxy-only
 # screen scan (``screen.SCAN_TILE`` = 16384, dp ~ 49), every fused tile
 # carries the exact [B, tile] GEMM over the full D, so the working set
@@ -88,121 +82,11 @@ def _merge_topm_carry(vals, idx, ex, neg_tile, idx_tile, ex_tile, m: int):
 
 def _tile_d2(q, xt, qn, xnt):
     """Clamped matmul-form squared distances for one tile (fp32)."""
-    dot = jax.lax.dot_general(
-        q, xt, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    return jnp.maximum(qn + xnt[None, :] - 2.0 * dot, 0.0)
+    return jnp.maximum(qn + xnt[None, :] - 2.0 * dot_f32(q, xt, ((1,), (1,))),
+                       0.0)
 
 
-# -- Pallas megakernel --------------------------------------------------------
-
-def _fused_kernel(qp_ref, xp_ref, q_ref, x_ref, qpn_ref, xpn_ref,
-                  qn_ref, xn_ref, idx_out, d2_out,
-                  vals_ref, idx_ref, ex_ref, *, m: int, bn: int, nn: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, -jnp.inf)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-        ex_ref[...] = jnp.full_like(ex_ref, jnp.inf)
-
-    pdot = jax.lax.dot_general(
-        qp_ref[...], xp_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    pd2 = jnp.maximum(qpn_ref[...] + xpn_ref[...] - 2.0 * pdot, 0.0)
-    edot = jax.lax.dot_general(
-        q_ref[...], x_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ed2 = jnp.maximum(qn_ref[...] + xn_ref[...] - 2.0 * edot, 0.0)
-    cols = j * bn + jax.lax.broadcasted_iota(jnp.int32, pd2.shape, 1)
-    new_v, new_i, new_e = _merge_topm_carry(
-        vals_ref[...], idx_ref[...], ex_ref[...], -pd2, cols, ed2, m)
-    vals_ref[...] = new_v
-    idx_ref[...] = new_i
-    ex_ref[...] = new_e
-
-    @pl.when(j == nn - 1)
-    def _emit():
-        idx_out[...] = idx_ref[...]
-        d2_out[...] = ex_ref[...]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("m", "bq", "bn", "interpret"))
-def fused_candidates_pallas(qp: jnp.ndarray, q: jnp.ndarray,
-                            proxy: jnp.ndarray, x: jnp.ndarray, m: int,
-                            proxy_norms: jnp.ndarray | None = None,
-                            x_norms: jnp.ndarray | None = None,
-                            bq: int = DEFAULT_BQ, bn: int = DEFAULT_TILE,
-                            interpret: bool = True):
-    """One-pass screened candidates with exact distances attached.
-
-    qp: [B, dp] proxy queries, q: [B, D] exact queries; proxy: [N, dp],
-    x: [N, D] -> ``(idx, d2)`` [B, m]: the proxy top-m candidate list
-    (ascending proxy distance, ``lax.top_k`` tie order) with each
-    slot's EXACT squared distance.  Surplus slots (m > N) carry
-    ``d2 = +inf`` and clamped indices.  interpret=True on CPU.
-
-    N is padded to a block multiple with +inf-norm rows on both stores
-    (the sibling-kernel idiom): padded rows screen last AND carry +inf
-    exact distance, so they can never acquire aggregation weight.
-    """
-    b, dp = qp.shape
-    d = q.shape[1]
-    n = x.shape[0]
-    qp32 = qp.astype(jnp.float32)
-    q32 = q.astype(jnp.float32)
-    qp_norms = jnp.sum(qp32 ** 2, -1)
-    q_norms = jnp.sum(q32 ** 2, -1)
-    if proxy_norms is None:
-        proxy_norms = jnp.sum(proxy.astype(jnp.float32) ** 2, -1)
-    if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
-
-    bq = min(bq, b)
-    bn = min(bn, max(n, 1))
-    pb = (-b) % bq
-    n_pad = max(-(-n // bn), -(-m // bn)) * bn
-    qpp = jnp.pad(qp32, ((0, pb), (0, 0)))
-    qxp = jnp.pad(q32, ((0, pb), (0, 0)))
-    xpp = jnp.pad(proxy, ((0, n_pad - n), (0, 0)))
-    xp = jnp.pad(x, ((0, n_pad - n), (0, 0)))
-    qpn = jnp.pad(qp_norms, (0, pb)).reshape(-1, 1)
-    qn = jnp.pad(q_norms, (0, pb)).reshape(-1, 1)
-    xpn = jnp.pad(proxy_norms.astype(jnp.float32), (0, n_pad - n),
-                  constant_values=jnp.inf).reshape(1, -1)
-    xn = jnp.pad(x_norms.astype(jnp.float32), (0, n_pad - n),
-                 constant_values=jnp.inf).reshape(1, -1)
-    nb, nn = (b + pb) // bq, n_pad // bn
-
-    idx, d2 = pl.pallas_call(
-        functools.partial(_fused_kernel, m=m, bn=bn, nn=nn),
-        grid=(nb, nn),
-        in_specs=[
-            pl.BlockSpec((bq, dp), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, dp), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=(pl.BlockSpec((bq, m), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bq, m), lambda i, j: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b + pb, m), jnp.int32),
-                   jax.ShapeDtypeStruct((b + pb, m), jnp.float32)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, m), jnp.float32),   # running negated proxy top-m
-            pltpu.VMEM((bq, m), jnp.int32),     # their dataset indices
-            pltpu.VMEM((bq, m), jnp.float32),   # their exact distances
-        ],
-        interpret=interpret,
-    )(qpp, xpp, qxp, xp, qpn, xpn, qn, xn)
-    return jnp.minimum(idx[:b], max(n - 1, 0)), d2[:b]
-
-
-# -- XLA (lax.scan) twin ------------------------------------------------------
+# -- lax.scan candidate pass -------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("m", "tile"))
 def fused_candidates_scan(qp: jnp.ndarray, q: jnp.ndarray,
@@ -210,7 +94,13 @@ def fused_candidates_scan(qp: jnp.ndarray, q: jnp.ndarray,
                           proxy_norms: jnp.ndarray | None = None,
                           x_norms: jnp.ndarray | None = None,
                           tile: int | None = None):
-    """Tiled-scan twin of :func:`fused_candidates_pallas` for any backend.
+    """One-pass screened candidates with exact distances attached.
+
+    qp: [B, dp] proxy queries, q: [B, D] exact queries; proxy: [N, dp],
+    x: [N, D] -> ``(idx, d2)`` [B, m]: the proxy top-m candidate list
+    (ascending proxy distance, ``lax.top_k`` tie order) with each
+    slot's EXACT squared distance.  Surplus slots (m > N) carry
+    ``d2 = +inf`` and clamped indices.
 
     Same ragged-tail handling as ``screen_topm_scan``: the final tile
     overlaps back (``dynamic_slice`` clamp) with re-seen proxy keys
@@ -261,8 +151,8 @@ def fused_candidates_scan(qp: jnp.ndarray, q: jnp.ndarray,
 # -- shared epilogue ----------------------------------------------------------
 
 def fused_posterior(x: jnp.ndarray, idx: jnp.ndarray, d2: jnp.ndarray,
-                    k: int, sigma2, backend: str = "xla",
-                    m_t=None, k_t=None, interpret: bool = True,
+                    k: int, sigma2, backend: str,
+                    m_t=None, k_t=None, interpret: bool = False,
                     strategy: str | None = None) -> jnp.ndarray:
     """Candidates + exact distances -> posterior mean [B, D] fp32.
 
@@ -297,4 +187,4 @@ def fused_posterior(x: jnp.ndarray, idx: jnp.ndarray, d2: jnp.ndarray,
         if (strategy or "gather") == "dense":
             return ref.scatter_aggregate_ref(x, gid, lg)
         return ref.golden_support_aggregate_ref(x[gid], lg)
-    return _sagg(x[gid], lg, interpret=interpret)
+    return _sagg(x, gid, lg, interpret=interpret)

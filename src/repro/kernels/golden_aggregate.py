@@ -19,10 +19,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
+from repro.kernels.common import VMEM_LIMIT_BYTES, dot_f32, row_tile
 
 NEG_INF = -1e30
 DEFAULT_BQ = 8
-DEFAULT_BN = 512
 
 
 def _agg_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref,
@@ -35,10 +35,8 @@ def _agg_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[...]
     x = x_ref[...]
-    dot = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    dot = dot_f32(q_ref[...], x, ((1,), (1,)))
     d2 = qn_ref[...] + xn_ref[...] - 2.0 * dot          # [bq, bn]
     # real rows clamp at the finite NEG_INF floor (extreme sigma -> a
     # uniform aggregate, never NaN); padded rows (d2 = +inf from the
@@ -52,8 +50,7 @@ def _agg_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref,
     scale = jnp.exp(m_prev - m_new)
     p = jnp.exp(logits - m_new)                          # [bq, bn]
     l_ref[...] = l_ref[...] * scale + jnp.sum(p, -1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * scale + jax.lax.dot(
-        p, x.astype(jnp.float32), preferred_element_type=jnp.float32)
+    acc_ref[...] = acc_ref[...] * scale + dot_f32(p, x, ((1,), (0,)))
     m_ref[...] = m_new
 
     @pl.when(j == nn - 1)
@@ -65,13 +62,14 @@ def _agg_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref,
 @functools.partial(jax.jit, static_argnames=("sigma2", "bq", "bn", "interpret"))
 def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
                      x_norms: jnp.ndarray | None = None,
-                     bq: int = DEFAULT_BQ, bn: int = DEFAULT_BN,
-                     interpret: bool = True) -> jnp.ndarray:
+                     bq: int = DEFAULT_BQ, bn: int | None = None,
+                     interpret: bool = False) -> jnp.ndarray:
     """Full-scan empirical-Bayes posterior mean.  q: [B, D], x: [N, D] -> [B, D].
 
     ``q`` must already be the rescaled query ``x_t / a_t``; ``sigma2`` is the
     noise-to-signal ratio sigma_t^2 (static: one program per timestep, the
-    per-step-jit execution mode of DESIGN §3).
+    per-step-jit execution mode of DESIGN §3).  ``bn=None`` sizes the
+    store tile to scoped VMEM (``common.row_tile``).
     """
     b, d = q.shape
     n = x.shape[0]
@@ -80,7 +78,7 @@ def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
     q_norms = jnp.sum(q.astype(jnp.float32) ** 2, -1)
 
     bq = min(bq, b)
-    bn = min(bn, n)
+    bn = row_tile(d, n) if bn is None else min(bn, n)
     pb = (-b) % bq
     pn = (-n) % bn
     qp = jnp.pad(q, ((0, pb), (0, 0)))
@@ -108,6 +106,9 @@ def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
             pltpu.VMEM((bq, d), jnp.float32),   # weighted accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(qp, xp, qn, xn)
     return out[:b]

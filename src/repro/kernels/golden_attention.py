@@ -67,7 +67,7 @@ def _gattn_kernel(idx_ref, valid_ref, q_ref, k_ref, v_ref, out_ref,
 def golden_attention_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                             block_idx: jnp.ndarray, valid: jnp.ndarray,
                             block_size: int = 128,
-                            interpret: bool = True) -> jnp.ndarray:
+                            interpret: bool = False) -> jnp.ndarray:
     """Exact attention over golden blocks only.
 
     q: [B, Hkv, G, dh]; k/v: [B, Hkv, S, dh]; block_idx/valid: [B, Hkv, kb].

@@ -11,13 +11,21 @@ through these wrappers so the same code path serves CPU tests, the
 multi-pod dry-run, and real TPUs.
 
 ``backend``:
-  * "pallas"            — lower the TPU kernel (real hardware)
-  * "pallas_interpret"  — execute the kernel body in Python on CPU
-                          (correctness validation; the tests use this)
-  * "xla"               — pure-jnp reference math (CPU benchmarks and
-                          the multi-pod dry-run, which compiles for the
-                          CPU backend where Pallas TPU kernels cannot
-                          lower)
+  * "pallas"            — the TPU implementation of every stage: the
+                          compiled Pallas kernels (``pdist``,
+                          ``centroid_scan``, the re-rank and aggregate
+                          kernels) plus the ``lax.scan`` forms of the
+                          streamed screen and the fused candidate pass,
+                          whose running top-m merge Mosaic cannot lower
+                          (it has no ``top_k``)
+  * "pallas_interpret"  — the same stage choices with the kernel bodies
+                          executed in Python on the CPU (correctness
+                          validation; reached only by naming it)
+  * "xla"               — pure-jnp reference math
+  * ``None``            — :func:`platform_backend`: "pallas" on a TPU,
+                          "xla" everywhere else.  The engine, the
+                          denoisers and every op here resolve a missing
+                          backend by this one rule.
 
 Strategy note (measured on XLA:CPU): row gathers run ~50x slower per
 element than GEMM, so by default the "xla" backend computes re-rank
@@ -31,9 +39,10 @@ GPU/TPU), which is exactly the regime the Golden Index creates, so
 ``strategy`` ("dense" | "gather") that ``GoldDiffEngine`` selects per
 platform at build time instead of hard-coding by backend.  The Pallas
 backends always use the tiled gather kernels, the right shape for TPU
-(MXU matmuls over VMEM tiles, DMA gathers).  All paths compute the same
-math with fp32 accumulation; parity is asserted in
-``tests/test_engine.py`` / ``tests/test_index.py``.
+(MXU matmuls over VMEM tiles).  All paths compute the same math with
+fp32 accumulation at ``HIGHEST`` matmul precision
+(``kernels.common``); parity is asserted in ``tests/test_engine.py`` /
+``tests/test_index.py``.
 
 ``ivf_screen`` + ``centroid_scan`` are the indexed (sublinear) coarse
 stage over a ``repro.index.GoldenIndex`` layout.
@@ -45,9 +54,9 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.centroid_scan import centroid_scan as _cscan
+from repro.kernels.common import dot_f32
 from repro.kernels.flash_attention import flash_attention as _flash
-from repro.kernels.fused_step import (fused_candidates_pallas,
-                                      fused_candidates_scan, fused_posterior)
+from repro.kernels.fused_step import fused_candidates_scan, fused_posterior
 from repro.kernels.golden_aggregate import golden_aggregate as _agg
 from repro.kernels.golden_attention import (golden_attention_decode as _gattn,
                                             select_golden_blocks)
@@ -57,11 +66,21 @@ from repro.kernels.golden_support_aggregate import (
 from repro.kernels.pdist import pdist as _pdist
 from repro.kernels.screen import (DEFAULT_TILE, SCAN_TILE,
                                   full_scan_partial_stream,
-                                  full_scan_stream, screen_topm_pallas,
-                                  screen_topm_scan)
+                                  full_scan_stream, screen_topm_scan)
 
-DEFAULT_BACKEND = "pallas_interpret"
 BACKENDS = ("pallas", "pallas_interpret", "xla")
+
+
+def platform_backend() -> str:
+    """The backend for the platform JAX runs on: the compiled kernels
+    ("pallas") on a TPU, the XLA reference math ("xla") elsewhere.
+    Interpret mode is never a default."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def _resolve(backend: str | None) -> str:
+    return platform_backend() if backend is None else backend
+
 
 # -- fault-injection dispatch seam -------------------------------------------
 # The engine's compiled-program cache (``GoldDiffEngine.program``)
@@ -94,18 +113,19 @@ def dispatch_hook():
     return _DISPATCH_HOOK
 
 
-def pdist(q, x, q_norms=None, x_norms=None, backend: str = DEFAULT_BACKEND,
+def pdist(q, x, q_norms=None, x_norms=None, backend: str | None = None,
           **kw):
     """Pairwise squared distances [B, N] (tiled matmul form, fp32)."""
+    backend = _resolve(backend)
     if backend == "xla":
         return ref.pdist_ref(q, x, q_norms, x_norms)
-    return _pdist(q, x, q_norms, x_norms, interpret=(backend != "pallas"),
-                  **kw)
+    return _pdist(q, x, q_norms, x_norms,
+                  interpret=(backend == "pallas_interpret"), **kw)
 
 
 def screen_topm(q, x, m: int, q_norms=None, x_norms=None,
                 tile: int | None = None, stream: bool = True,
-                backend: str = DEFAULT_BACKEND, **kw):
+                backend: str | None = None):
     """Exact top-m rows of x by squared distance, read exactly once.
 
     The streaming coarse screen (``kernels.screen``): tiled matmul-form
@@ -121,31 +141,21 @@ def screen_topm(q, x, m: int, q_norms=None, x_norms=None,
     engine's streamed-vs-materialized crossover, where one big GEMM
     beats the scan's per-tile merge overhead (measured ~1.6x on
     XLA:CPU at the scan-path default tile; see
-    ``benchmarks/screen_speedup.py``).  ``tile=None`` resolves per
-    path: ``SCAN_TILE`` for the lax.scan fallback, ``DEFAULT_TILE``
-    for the Pallas VMEM block.
+    ``benchmarks/screen_speedup.py``).  The streamed form is
+    ``screen_topm_scan`` on every backend (Mosaic cannot lower its
+    top-m merge); ``tile=None`` picks its ``SCAN_TILE``.
     """
+    backend = _resolve(backend)
     if not stream:
         if backend == "xla":
             return ref.screen_topm_ref(q, x, m, q_norms, x_norms)
         return ref.materialized_topm(
             pdist(q, x, q_norms, x_norms, backend=backend), m)
-    if backend == "xla":
-        return screen_topm_scan(q, x, m, q_norms, x_norms, tile=tile)
-    return screen_topm_pallas(q, x, m, q_norms, x_norms,
-                              bn=DEFAULT_TILE if tile is None else tile,
-                              interpret=(backend != "pallas"), **kw)
-
-
-def support_sqdist(q, xs, x_norms, backend: str = DEFAULT_BACKEND, **kw):
-    """Distances to per-query gathered rows: [B, M, D] -> [B, M] fp32."""
-    if backend == "xla":
-        return ref.support_sqdist_ref(q, xs, x_norms)
-    return _sqd(q, xs, x_norms, interpret=(backend != "pallas"), **kw)
+    return screen_topm_scan(q, x, m, q_norms, x_norms, tile=tile)
 
 
 def support_distances(q, x, idx, x_norms=None,
-                      backend: str = DEFAULT_BACKEND,
+                      backend: str | None = None,
                       strategy: str | None = None, **kw):
     """Exact distances q -> x[idx] with no [B, m, D] subtract temporaries.
 
@@ -156,6 +166,7 @@ def support_distances(q, x, idx, x_norms=None,
     xla).  The pallas backends always gather — tiled VMEM kernels are
     the TPU shape regardless.
     """
+    backend = _resolve(backend)
     if x_norms is None:
         x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
     if backend == "xla":
@@ -163,11 +174,12 @@ def support_distances(q, x, idx, x_norms=None,
             d2_all = ref.pdist_ref(q, x, x_norms=x_norms)
             return jnp.take_along_axis(d2_all, idx, axis=-1)
         return ref.support_sqdist_ref(q, x[idx], x_norms[idx])
-    return support_sqdist(q, x[idx], x_norms[idx], backend=backend, **kw)
+    return _sqd(q, x, idx, x_norms,
+                interpret=(backend == "pallas_interpret"), **kw)
 
 
 def golden_rerank(q, x, cand, k: int, x_norms=None,
-                  backend: str = DEFAULT_BACKEND,
+                  backend: str | None = None,
                   strategy: str | None = None, valid=None, **kw):
     """Exact re-rank inside the candidate set (paper Eq. 5).
 
@@ -188,9 +200,9 @@ def golden_rerank(q, x, cand, k: int, x_norms=None,
 
 def fused_step(q, qp, x, proxy, m: int, k: int, sigma2,
                x_norms=None, proxy_norms=None,
-               backend: str = DEFAULT_BACKEND, strategy: str | None = None,
+               backend: str | None = None, strategy: str | None = None,
                stream: bool = True, tile: int | None = None,
-               m_t=None, k_t=None, **kw):
+               m_t=None, k_t=None):
     """One fused GoldDiff denoise step: posterior mean in a single pass.
 
     Coarse screen + exact re-rank + softmax aggregation fused
@@ -207,22 +219,19 @@ def fused_step(q, qp, x, proxy, m: int, k: int, sigma2,
     "gather" keeps everything sublinear in N (the streaming story);
     "dense" keeps the scatter + GEMM aggregate dense-strategy engines
     already use — the identical op the staged body runs, so fused and
-    staged stay op-compatible per strategy.  ``stream=False`` (xla
-    only) keeps the materialized candidate form below the
-    streamed-screen byte crossover.  The pallas backends always stream
-    (the megakernel is the TPU shape).  ``sigma2`` may be traced;
+    staged stay op-compatible per strategy.  ``stream=False`` keeps the
+    materialized candidate form below the streamed-screen byte
+    crossover.  The streamed candidate pass is ``fused_candidates_scan``
+    on every backend (Mosaic cannot lower its top-m merge); on the
+    pallas backends the epilogue aggregates with the support-aggregate
+    kernel.  ``sigma2`` may be traced;
     ``m_t`` / ``k_t`` (optional traced scalars) mask scheduled sizes
     for the caps-aware masked path.  Fused-vs-staged outputs agree at
     fp32 reduction order (~1e-7 relative; the candidate *sets* are
     bit-identical, see the kernel module docstring).
     """
-    interpret = backend != "pallas"
-    if backend != "xla":
-        idx, d2 = fused_candidates_pallas(
-            qp, q, proxy, x, m, proxy_norms, x_norms,
-            bn=DEFAULT_TILE if tile is None else tile,
-            interpret=interpret, **kw)
-    elif stream:
+    backend = _resolve(backend)
+    if stream:
         idx, d2 = fused_candidates_scan(qp, q, proxy, x, m,
                                         proxy_norms, x_norms, tile=tile)
     else:
@@ -235,11 +244,12 @@ def fused_step(q, qp, x, proxy, m: int, k: int, sigma2,
         # weightless, matching the streaming forms
         d2 = jnp.where(jnp.isinf(pd2), jnp.inf, d2)
     return fused_posterior(x, idx, d2, k, sigma2, backend=backend,
-                           m_t=m_t, k_t=k_t, interpret=interpret,
+                           m_t=m_t, k_t=k_t,
+                           interpret=(backend == "pallas_interpret"),
                            strategy=strategy)
 
 
-def golden_support_aggregate(x, idx, logits, backend: str = DEFAULT_BACKEND,
+def golden_support_aggregate(x, idx, logits, backend: str | None = None,
                              strategy: str | None = None, **kw):
     """softmax(logits)-weighted mean of x[idx] per query -> [B, D] fp32.
 
@@ -249,11 +259,13 @@ def golden_support_aggregate(x, idx, logits, backend: str = DEFAULT_BACKEND,
     (``strategy="gather"``, sublinear in N); pallas*: gather + streaming
     online-softmax kernel.
     """
+    backend = _resolve(backend)
     if backend == "xla":
         if (strategy or "dense") == "dense":
             return ref.scatter_aggregate_ref(x, idx, logits)
         return ref.golden_support_aggregate_ref(x[idx], logits)
-    return _sagg(x[idx], logits, interpret=(backend != "pallas"), **kw)
+    return _sagg(x, idx, logits, interpret=(backend == "pallas_interpret"),
+                 **kw)
 
 
 def golden_partial_aggregate(x, idx, logits, strategy: str | None = None):
@@ -279,9 +291,7 @@ def golden_partial_aggregate(x, idx, logits, strategy: str | None = None):
         lg = logits.astype(jnp.float32)
         m = jnp.max(lg, axis=-1)
         p = jnp.exp(lg - m[:, None])
-        return (jax.lax.dot_general(
-            p, x.astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32), m, jnp.sum(p, axis=-1))
+        return dot_f32(p, x, ((1,), (0,))), m, jnp.sum(p, axis=-1)
     if (strategy or "gather") == "dense":
         return ref.scatter_partial_aggregate_ref(x, idx, logits)
     return ref.partial_aggregate_ref(x[idx], logits)
@@ -290,7 +300,7 @@ def golden_partial_aggregate(x, idx, logits, strategy: str | None = None):
 def ivf_screen_local(qp, offsets_loc, centroids, centroid_norms, w_lo, w_hi,
                      nprobe_max: int, max_cluster: int, w_cap: int,
                      n_loc: int, nprobe=None,
-                     backend: str = DEFAULT_BACKEND):
+                     backend: str | None = None):
     """Shard-local lanes of a *globally probed* Golden Index.
 
     The sharded engine partitions one global ``GoldenIndex`` across
@@ -334,18 +344,19 @@ def ivf_screen_local(qp, offsets_loc, centroids, centroid_norms, w_lo, w_hi,
     return pos, jnp.where(valid, 0.0, jnp.inf)
 
 
-def centroid_scan(q, centroids, c_norms=None, backend: str = DEFAULT_BACKEND,
+def centroid_scan(q, centroids, c_norms=None, backend: str | None = None,
                   **kw):
     """Query -> k-means-centroid distances [B, C] (IVF level 1, fp32)."""
+    backend = _resolve(backend)
     if backend == "xla":
         return ref.pdist_ref(q, centroids, x_norms=c_norms)
-    return _cscan(q, centroids, c_norms, interpret=(backend != "pallas"),
-                  **kw)
+    return _cscan(q, centroids, c_norms,
+                  interpret=(backend == "pallas_interpret"), **kw)
 
 
 def ivf_screen(qp, proxy_sorted, proxy_norms_sorted, offsets, centroids,
                centroid_norms, m: int, nprobe_max: int, max_cluster: int,
-               nprobe=None, backend: str = DEFAULT_BACKEND, **kw):
+               nprobe=None, backend: str | None = None):
     """Two-level indexed coarse screening (GoldenIndex layout).
 
     Level 1: tiled centroid scan + top-``nprobe_max`` probe selection.
@@ -388,16 +399,15 @@ def ivf_screen(qp, proxy_sorted, proxy_norms_sorted, offsets, centroids,
     valid = valid.reshape(b, -1)
     if m >= nprobe_max * max_cluster:
         return pos, jnp.where(valid, 0.0, jnp.inf)
-    xs = proxy_sorted[pos]                                  # [B, R, dp]
-    xn = proxy_norms_sorted[pos]
-    d2 = support_sqdist(qp, xs, xn, backend=backend, **kw)
+    d2 = support_distances(qp, proxy_sorted, pos, proxy_norms_sorted,
+                           backend=backend, strategy="gather")
     d2 = jnp.where(valid, d2, jnp.inf)
     neg, sel = jax.lax.top_k(-d2, m)
     return jnp.take_along_axis(pos, sel, axis=-1), -neg
 
 
 def golden_aggregate(q, x, sigma2: float, x_norms=None,
-                     backend: str = DEFAULT_BACKEND, stream: bool = False,
+                     backend: str | None = None, stream: bool = False,
                      tile: int | None = None, **kw):
     """Full-scan posterior mean (Eq. 2) via streaming softmax.
 
@@ -407,6 +417,7 @@ def golden_aggregate(q, x, sigma2: float, x_norms=None,
     (``kernels.screen.full_scan_stream``), which makes full-scan
     baselines runnable at N where the dense matrix cannot be allocated.
     """
+    backend = _resolve(backend)
     if backend == "xla":
         if stream:
             return full_scan_stream(q, x, float(sigma2), x_norms=x_norms,
@@ -414,7 +425,7 @@ def golden_aggregate(q, x, sigma2: float, x_norms=None,
                                     else tile)
         return ref.golden_aggregate_ref(q, x, sigma2, x_norms)
     return _agg(q, x, float(sigma2), x_norms=x_norms,
-                interpret=(backend != "pallas"), **kw)
+                interpret=(backend == "pallas_interpret"), **kw)
 
 
 def golden_full_partial(q, x, sigma2: float, x_norms=None,
@@ -441,27 +452,30 @@ def golden_full_partial(q, x, sigma2: float, x_norms=None,
 
 
 def golden_attention_decode(q, k, v, block_idx, valid, block_size: int = 128,
-                            backend: str = DEFAULT_BACKEND):
+                            backend: str | None = None):
+    backend = _resolve(backend)
     if backend == "xla":
         return ref.golden_attention_decode_ref(q, k, v, block_idx, valid,
                                                block_size)
     return _gattn(q, k, v, block_idx, valid, block_size=block_size,
-                  interpret=(backend != "pallas"))
+                  interpret=(backend == "pallas_interpret"))
 
 
 def flash_attention(q, k, v, causal: bool = True,
-                    backend: str = DEFAULT_BACKEND, **kw):
+                    backend: str | None = None, **kw):
+    backend = _resolve(backend)
     if backend == "xla":
         return ref.flash_attention_ref(q, k, v, causal)
-    return _flash(q, k, v, causal=causal, interpret=(backend != "pallas"),
-                  **kw)
+    return _flash(q, k, v, causal=causal,
+                  interpret=(backend == "pallas_interpret"), **kw)
 
 
-__all__ = ["pdist", "screen_topm", "support_sqdist", "support_distances",
-           "golden_rerank", "fused_step", "fused_posterior",
+__all__ = ["pdist", "screen_topm", "support_distances",
+           "golden_rerank", "fused_step",
            "golden_support_aggregate",
            "golden_partial_aggregate", "golden_full_partial",
            "golden_aggregate", "centroid_scan", "ivf_screen",
            "ivf_screen_local", "golden_attention_decode",
-           "select_golden_blocks", "flash_attention", "DEFAULT_BACKEND",
-           "BACKENDS", "DEFAULT_TILE", "SCAN_TILE", "set_dispatch_hook", "dispatch_hook"]
+           "select_golden_blocks", "flash_attention", "platform_backend",
+           "BACKENDS", "DEFAULT_TILE", "SCAN_TILE", "set_dispatch_hook",
+           "dispatch_hook"]

@@ -18,15 +18,14 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import dot_f32
+
 DEFAULT_BQ = 128
 DEFAULT_BN = 512
 
 
 def _pdist_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref):
-    q = q_ref[...]
-    x = x_ref[...]
-    acc = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    acc = dot_f32(q_ref[...], x_ref[...], ((1,), (1,)))
     d2 = qn_ref[...] + xn_ref[...] - 2.0 * acc
     out_ref[...] = jnp.maximum(d2, 0.0)
 
@@ -36,10 +35,10 @@ def pdist(q: jnp.ndarray, x: jnp.ndarray,
           q_norms: jnp.ndarray | None = None,
           x_norms: jnp.ndarray | None = None,
           bq: int = DEFAULT_BQ, bn: int = DEFAULT_BN,
-          interpret: bool = True) -> jnp.ndarray:
+          interpret: bool = False) -> jnp.ndarray:
     """||q_i - x_j||^2 for q: [B, d], x: [N, d] -> [B, N] (fp32).
 
-    interpret=True on CPU (validation); False lowers for real TPUs.
+    ``interpret=True`` runs the kernel body on the CPU (validation only).
     """
     b, d = q.shape
     n = x.shape[0]

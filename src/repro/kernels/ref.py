@@ -4,6 +4,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.common import HIGHEST, dot_f32
+
 NEG_INF = -1e30
 
 # Largest inverse-temperature the aggregation softmax will use: chosen
@@ -39,7 +41,7 @@ def pdist_ref(q: jnp.ndarray, x: jnp.ndarray,
     x = x.astype(jnp.float32)
     qn = jnp.sum(q * q, -1) if q_norms is None else q_norms.astype(jnp.float32)
     xn = jnp.sum(x * x, -1) if x_norms is None else x_norms.astype(jnp.float32)
-    d2 = qn[:, None] + xn[None, :] - 2.0 * q @ x.T
+    d2 = qn[:, None] + xn[None, :] - 2.0 * dot_f32(q, x, ((1,), (1,)))
     return jnp.maximum(d2, 0.0)
 
 
@@ -82,7 +84,7 @@ def support_sqdist_ref(q: jnp.ndarray, xs: jnp.ndarray,
     xn = (jnp.sum(xs32 * xs32, -1) if x_norms is None
           else x_norms.astype(jnp.float32))
     qn = jnp.sum(q32 * q32, -1, keepdims=True)
-    dot = jnp.einsum("bd,bmd->bm", q32, xs32)
+    dot = jnp.einsum("bd,bmd->bm", q32, xs32, precision=HIGHEST)
     return jnp.maximum(qn + xn - 2.0 * dot, 0.0)
 
 
@@ -95,9 +97,7 @@ def golden_aggregate_ref(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
     inv = finite_inv_two_sigma2(sigma2)
     lg = jnp.maximum(-pdist_ref(q, x, x_norms=x_norms) * inv, NEG_INF)
     w = jax.nn.softmax(lg, axis=-1)
-    out = jax.lax.dot_general(w, x, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    return dot_f32(w, x, ((1,), (0,))).astype(q.dtype)
 
 
 def scatter_aggregate_ref(x: jnp.ndarray, idx: jnp.ndarray,
@@ -114,15 +114,15 @@ def scatter_aggregate_ref(x: jnp.ndarray, idx: jnp.ndarray,
     w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     ws = jnp.zeros((b, n), jnp.float32).at[
         jnp.arange(b)[:, None], idx].add(w)
-    return jax.lax.dot_general(ws, x, (((1,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
+    return dot_f32(ws, x, ((1,), (0,)))
 
 
 def golden_support_aggregate_ref(xs: jnp.ndarray,
                                  logits: jnp.ndarray) -> jnp.ndarray:
     """Gathered-values oracle for the Pallas support-aggregate kernel."""
     w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.einsum("bk,bkd->bd", w, xs.astype(jnp.float32))
+    return jnp.einsum("bk,bkd->bd", w, xs.astype(jnp.float32),
+                      precision=HIGHEST)
 
 
 def partial_aggregate_ref(xs: jnp.ndarray, logits: jnp.ndarray
@@ -140,7 +140,8 @@ def partial_aggregate_ref(xs: jnp.ndarray, logits: jnp.ndarray
     m = jnp.max(lg, axis=-1)
     p = jnp.exp(lg - m[:, None])
     l = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bk,bkd->bd", p, xs.astype(jnp.float32))
+    acc = jnp.einsum("bk,bkd->bd", p, xs.astype(jnp.float32),
+                     precision=HIGHEST)
     return acc, m, l
 
 
@@ -157,9 +158,7 @@ def scatter_partial_aggregate_ref(x: jnp.ndarray, idx: jnp.ndarray,
     l = jnp.sum(p, axis=-1)
     ws = jnp.zeros((b, n), jnp.float32).at[
         jnp.arange(b)[:, None], idx].add(p)
-    acc = jax.lax.dot_general(ws, x, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    return acc, m, l
+    return dot_f32(ws, x, ((1,), (0,))), m, l
 
 
 def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

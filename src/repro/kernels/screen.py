@@ -21,15 +21,13 @@ left-to-right, ties resolve to the lowest dataset index — exactly
 materialized ``lax.top_k(-pdist, m)`` bit-for-bit (per-element distance
 dot products reduce over d in the same order regardless of N tiling).
 
-Three implementations share that math:
+Two implementations share that math:
 
-* ``screen_topm_pallas`` — Pallas TPU kernel: flash-attention-style
-  carry of (values, indices) scratch across the N grid axis, one
-  matmul + merge per VMEM tile.  (Like the other engine kernels it is
-  validated in interpret mode; the in-kernel ``lax.top_k`` lowering on
-  real Mosaic is part of the ROADMAP real-TPU item.)
-* ``screen_topm_scan``   — XLA fallback: ``lax.scan`` over N-tiles with
-  the same carry; compiles for any backend.
+* ``screen_topm_scan``   — ``lax.scan`` over N-tiles with the carry; it
+  is the streamed screen on every backend, the TPU included.  Mosaic
+  has no ``top_k`` (nor a sort) to lower the merge inside a Pallas
+  kernel, so a Pallas form of this screen cannot compile for the chip;
+  XLA runs the tile GEMM and the merge as ordinary TPU ops.
 * ``ref.screen_topm_ref`` — materialized oracle (pdist + top_k).
 
 ``full_scan_partial_stream`` applies the identical tiling to the exact
@@ -49,17 +47,15 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
+from repro.kernels.common import dot_f32
 
 NEG_INF = -1e30
-DEFAULT_BQ = 8
 DEFAULT_TILE = 4096
-# Scan-path default N-tile.  The lax.scan fallback holds only [B, tile]
-# live distances, so it affords a 4x larger tile than the Pallas VMEM
-# block — and XLA:CPU wall-clock improves monotonically with tile size
+# Scan-path default N-tile.  The lax.scan form holds only [B, tile]
+# live distances, so it affords a 4x larger tile than DEFAULT_TILE —
+# and XLA:CPU wall-clock improves monotonically with tile size
 # (fewer merge dispatches, fatter GEMMs): at N=65536, B=32 the carry
 # merge measures 42/118/421 ms (m=512/1638/6553) at tile=4096 vs
 # 33/64/204 ms at 16384, recovering most of the streamed-vs-
@@ -81,112 +77,24 @@ def _merge_topm(vals, idx, neg_tile, idx_tile, m: int):
     return new_v, jnp.take_along_axis(cat_i, sel, axis=-1)
 
 
-# -- Pallas kernel ------------------------------------------------------------
-
-def _screen_kernel(q_ref, x_ref, qn_ref, xn_ref, idx_out, d2_out,
-                   vals_ref, idx_ref, *, m: int, bn: int, nn: int):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        vals_ref[...] = jnp.full_like(vals_ref, -jnp.inf)
-        idx_ref[...] = jnp.zeros_like(idx_ref)
-
-    q = q_ref[...]
-    x = x_ref[...]
-    dot = jax.lax.dot_general(
-        q, x, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    d2 = jnp.maximum(qn_ref[...] + xn_ref[...] - 2.0 * dot, 0.0)
-    base = j * bn
-    cols = base + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
-    new_v, new_i = _merge_topm(vals_ref[...], idx_ref[...], -d2, cols, m)
-    vals_ref[...] = new_v
-    idx_ref[...] = new_i
-
-    @pl.when(j == nn - 1)
-    def _emit():
-        idx_out[...] = idx_ref[...]
-        d2_out[...] = -vals_ref[...]
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("m", "bq", "bn", "interpret"))
-def screen_topm_pallas(q: jnp.ndarray, x: jnp.ndarray, m: int,
-                       q_norms: jnp.ndarray | None = None,
-                       x_norms: jnp.ndarray | None = None,
-                       bq: int = DEFAULT_BQ, bn: int = DEFAULT_TILE,
-                       interpret: bool = True):
-    """Streaming top-m over x for q: [B, d], x: [N, d] -> (idx, d2) [B, m].
-
-    ``d2`` ascending fp32; +inf marks slots past the real rows (m > N),
-    whose indices are clamped in-range.  interpret=True on CPU.
-
-    Like the sibling Pallas kernels (``pdist``, ``golden_aggregate``)
-    the N axis is explicitly padded to a block multiple with +inf-norm
-    rows — an HBM-side copy when N % bn != 0, the established idiom
-    here.  The XLA scan twin avoids even that (clamped overlapping
-    tiles); callers who need strict O(B (m + tile)) memory on ragged N
-    use it.
-    """
-    b, d = q.shape
-    n = x.shape[0]
-    if q_norms is None:
-        q_norms = jnp.sum(q.astype(jnp.float32) ** 2, -1)
-    if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
-
-    bq = min(bq, b)
-    bn = min(bn, max(n, 1))
-    pb = (-b) % bq
-    # pad N so every tile is full AND the carry always holds m slots
-    n_pad = max(-(-n // bn), -(-m // bn)) * bn
-    qp = jnp.pad(q, ((0, pb), (0, 0)))
-    xp = jnp.pad(x, ((0, n_pad - n), (0, 0)))
-    qn = jnp.pad(q_norms, (0, pb)).reshape(-1, 1)
-    # +inf norms on padded rows -> +inf distance -> selected last
-    xn = jnp.pad(x_norms.astype(jnp.float32), (0, n_pad - n),
-                 constant_values=jnp.inf).reshape(1, -1)
-    nb, nn = (b + pb) // bq, n_pad // bn
-
-    idx, d2 = pl.pallas_call(
-        functools.partial(_screen_kernel, m=m, bn=bn, nn=nn),
-        grid=(nb, nn),
-        in_specs=[
-            pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((1, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=(pl.BlockSpec((bq, m), lambda i, j: (i, 0)),
-                   pl.BlockSpec((bq, m), lambda i, j: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((b + pb, m), jnp.int32),
-                   jax.ShapeDtypeStruct((b + pb, m), jnp.float32)),
-        scratch_shapes=[
-            pltpu.VMEM((bq, m), jnp.float32),   # running negated top-m
-            pltpu.VMEM((bq, m), jnp.int32),     # their dataset indices
-        ],
-        interpret=interpret,
-    )(qp, xp, qn, xn)
-    return jnp.minimum(idx[:b], max(n - 1, 0)), d2[:b]
-
-
-# -- XLA (lax.scan) fallback --------------------------------------------------
+# -- lax.scan streamed screen ------------------------------------------------
 
 @functools.partial(jax.jit, static_argnames=("m", "tile", "hier"))
 def screen_topm_scan(q: jnp.ndarray, x: jnp.ndarray, m: int,
                      q_norms: jnp.ndarray | None = None,
                      x_norms: jnp.ndarray | None = None,
                      tile: int | None = None, hier: bool = False):
-    """Tiled-scan twin of :func:`screen_topm_pallas` for any XLA backend.
+    """Streaming top-m over x for q: [B, d], x: [N, d] -> (idx, d2) [B, m].
 
-    Peak live memory O(B * (m + tile)); the [N, d] store is sliced in
-    place (``dynamic_slice``), never padded or re-materialized — a
+    ``d2`` ascending fp32; +inf marks slots past the real rows (m > N),
+    whose indices are clamped in-range.  Peak live memory
+    O(B * (m + tile)); the [N, d] store is sliced in place
+    (``dynamic_slice``), never padded or re-materialized — a
     ragged final tile slides back to ``[N - tile, N)`` (the
     dynamic-slice clamp) and the already-seen overlap columns are
     masked to -inf, so no O(N d) padded copy exists for any N.
-    ``tile=None`` picks :data:`SCAN_TILE` (the scan path affords a much
-    larger tile than the Pallas VMEM block, and CPU wall-clock improves
-    with it — see the constant's comment).
+    ``tile=None`` picks :data:`SCAN_TILE` (CPU wall-clock improves with
+    the tile — see the constant's comment).
 
     ``hier=True`` switches the merge to a two-level hierarchical form:
     each tile selects its own top-m independently inside the scan, the
@@ -222,9 +130,7 @@ def screen_topm_scan(q: jnp.ndarray, x: jnp.ndarray, m: int,
         eff = jnp.minimum(start, n - tile)     # ragged tail: overlap back
         xt = jax.lax.dynamic_slice_in_dim(x, eff, tile).astype(jnp.float32)
         xnt = jax.lax.dynamic_slice_in_dim(x_norms, eff, tile)
-        dot = jax.lax.dot_general(
-            q32, xt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dot = dot_f32(q32, xt, ((1,), (1,)))
         d2 = jnp.maximum(qn + xnt[None, :] - 2.0 * dot, 0.0)
         cols = eff + jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1)
         return jnp.where(cols >= start, -d2, -jnp.inf), cols  # mask re-seen
@@ -297,9 +203,7 @@ def full_scan_partial_stream(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
         eff = jnp.minimum(start, n - tile)     # ragged tail: overlap back
         xt = jax.lax.dynamic_slice_in_dim(x, eff, tile).astype(jnp.float32)
         xnt = jax.lax.dynamic_slice_in_dim(x_norms, eff, tile)
-        dot = jax.lax.dot_general(
-            q32, xt, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        dot = dot_f32(q32, xt, ((1,), (1,)))
         d2 = jnp.maximum(qn + xnt[None, :] - 2.0 * dot, 0.0)
         # +inf-norm (padded) rows clamp to the finite NEG_INF sentinel —
         # exp(NEG_INF - m) underflows to exactly 0 for any real logit,
@@ -312,9 +216,7 @@ def full_scan_partial_stream(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
         scale = jnp.exp(m_run - m_new)
         p = jnp.exp(lg - m_new[:, None])
         l_new = l_run * scale + jnp.sum(p, -1)
-        acc_new = acc * scale[:, None] + jax.lax.dot_general(
-            p, xt, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        acc_new = acc * scale[:, None] + dot_f32(p, xt, ((1,), (0,)))
         return (m_new, l_new, acc_new), None
 
     init = (jnp.full((b,), NEG_INF, jnp.float32),

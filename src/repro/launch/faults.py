@@ -45,15 +45,10 @@ import time
 
 import jax
 import numpy as np
+from jax.errors import JaxRuntimeError as XlaRuntimeError
 
 from repro.kernels import ops
 from repro.obs import trace as obs_trace
-
-try:                                     # jax >= 0.4.14
-    from jax.errors import JaxRuntimeError as XlaRuntimeError
-except ImportError:                      # pragma: no cover - older jax
-    from jax._src.lib import xla_client
-    XlaRuntimeError = xla_client.XlaRuntimeError
 
 
 class TransientExecutorError(RuntimeError):

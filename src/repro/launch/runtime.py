@@ -490,68 +490,77 @@ class ServeRuntime:
         scan-mode programs, and the Gaussian fallback segments.  After
         this, NO failure path touches the compiler (``health()`` tracks
         ``compiles_post_warmup`` via the engine's build counter, which
-        counts evict-driven rebuilds a cache-size delta would miss)."""
+        counts evict-driven rebuilds a cache-size delta would miss).
+        The AOT programs compile side by side in the engine's
+        ``parallel_compiles`` pool."""
         t0 = time.time()
-        stats = self.eng.warmup()
-        aot = self.engine.mesh is None
-        dim = self.eng.store.dim
-        call_masked = self.eng.denoiser.call_masked \
-            if self.eng._scan_compatible() else None
-        nts_set = {self.eng.num_steps + 1}
-        for p in self.plans.values():
-            nts_set.add(len(p.ts))
-        for b in self.eng.batch_buckets():
-            shape = (b, dim)
-            if call_masked is not None:
-                # the scan rung (plan-mode engines don't warm it)
-                fn = self.eng._scan_program(shape, compile_only=aot)
-                if not aot:
-                    jax.block_until_ready(fn(jnp.zeros(shape, jnp.float32)))
-            seen = {id(self.eng.plan)} if self.eng.mode == "plan" else set()
-            for plan in self.plans.values():
-                if id(plan) in seen:
-                    continue
-                seen.add(id(plan))
-                sample_plan(call_masked, self.eng.schedule, shape,
-                            jax.random.PRNGKey(0), plan,
-                            clip_value=self.eng.clip_value,
-                            x_init=(None if aot
-                                    else jnp.zeros(shape, jnp.float32)),
-                            program_cache=self.engine.program,
-                            compile_only=aot, jitter=self.engine.jitter)
-            # mixed-cursor (continuous-batching) segments: one program
-            # per plan bucket per plan variant — including the primary
-            # plan, whose PLAIN segments eng.warmup() already compiled
-            seen_mix: set[int] = set()
-            for plan in (self.plans.values()
-                         if self.eng.mode == "plan" else ()):
-                if id(plan) in seen_mix:
-                    continue
-                seen_mix.add(id(plan))
-                for pb in plan.buckets:
-                    fn = self._mixed_program(b, plan, pb, compile_only=aot)
-                    if not aot:
-                        jax.block_until_ready(fn(
-                            jnp.zeros(shape, jnp.float32),
-                            jnp.full((b,), pb.start, jnp.int32)))
-            for nts in sorted(nts_set):
-                ts = np.arange(nts, dtype=np.int32)[::-1].copy()
-                ts = ts * 0 + 1              # any valid grid; compile only
-                fn = self._gauss_program(b, nts)
-                jax.block_until_ready(
-                    fn(jnp.zeros(shape, jnp.float32),
-                       jnp.asarray(ts, jnp.int32), np.int32(0), np.int32(1)))
-        if self.monitor is not None:
-            # recall probes fire at executed-step timesteps of any plan
-            # variant (and the scan grid): warm every one of them so
-            # monitoring never costs a post-warmup compile
-            probe_ts: set[int] = set()
+        with self.engine.parallel_compiles():
+            stats = self.eng.warmup()
+            aot = self.engine.mesh is None
+            dim = self.eng.store.dim
+            call_masked = self.eng.denoiser.call_masked \
+                if self.eng._scan_compatible() else None
+            nts_set = {self.eng.num_steps + 1}
             for p in self.plans.values():
-                probe_ts.update(int(t) for t in p.ts[:-1])
-            scan_ts = sampling_timesteps(self.eng.schedule,
-                                         self.eng.num_steps)
-            probe_ts.update(int(t) for t in scan_ts[:-1])
-            stats["probe_ts_warmed"] = self.monitor.warmup(sorted(probe_ts))
+                nts_set.add(len(p.ts))
+            for b in self.eng.batch_buckets():
+                shape = (b, dim)
+                if call_masked is not None:
+                    # the scan rung (plan-mode engines don't warm it)
+                    fn = self.eng._scan_program(shape, compile_only=aot)
+                    if not aot:
+                        jax.block_until_ready(
+                            fn(jnp.zeros(shape, jnp.float32)))
+                seen = ({id(self.eng.plan)} if self.eng.mode == "plan"
+                        else set())
+                for plan in self.plans.values():
+                    if id(plan) in seen:
+                        continue
+                    seen.add(id(plan))
+                    sample_plan(call_masked, self.eng.schedule, shape,
+                                jax.random.PRNGKey(0), plan,
+                                clip_value=self.eng.clip_value,
+                                x_init=(None if aot
+                                        else jnp.zeros(shape, jnp.float32)),
+                                program_cache=self.engine.program,
+                                compile_only=aot, jitter=self.engine.jitter)
+                # mixed-cursor (continuous-batching) segments: one
+                # program per plan bucket per plan variant — including
+                # the primary plan, whose PLAIN segments eng.warmup()
+                # already compiled
+                seen_mix: set[int] = set()
+                for plan in (self.plans.values()
+                             if self.eng.mode == "plan" else ()):
+                    if id(plan) in seen_mix:
+                        continue
+                    seen_mix.add(id(plan))
+                    for pb in plan.buckets:
+                        fn = self._mixed_program(b, plan, pb,
+                                                 compile_only=aot)
+                        if not aot:
+                            jax.block_until_ready(fn(
+                                jnp.zeros(shape, jnp.float32),
+                                jnp.full((b,), pb.start, jnp.int32)))
+                for nts in sorted(nts_set):
+                    ts = np.arange(nts, dtype=np.int32)[::-1].copy()
+                    ts = ts * 0 + 1          # any valid grid; compile only
+                    fn = self._gauss_program(b, nts)
+                    jax.block_until_ready(
+                        fn(jnp.zeros(shape, jnp.float32),
+                           jnp.asarray(ts, jnp.int32), np.int32(0),
+                           np.int32(1)))
+            if self.monitor is not None:
+                # recall probes fire at executed-step timesteps of any
+                # plan variant (and the scan grid): warm every one of
+                # them so monitoring never costs a post-warmup compile
+                probe_ts: set[int] = set()
+                for p in self.plans.values():
+                    probe_ts.update(int(t) for t in p.ts[:-1])
+                scan_ts = sampling_timesteps(self.eng.schedule,
+                                             self.eng.num_steps)
+                probe_ts.update(int(t) for t in scan_ts[:-1])
+                stats["probe_ts_warmed"] = self.monitor.warmup(
+                    sorted(probe_ts))
         self._warm = True
         self._builds_warm = self.engine._builds
         stats["runtime_warmup_s"] = time.time() - t0

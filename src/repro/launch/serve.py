@@ -59,6 +59,7 @@ from repro.core.schedules import sampling_timesteps
 from repro.data import make_dataset
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.utils import enable_compile_cache
 
 
 @dataclasses.dataclass
@@ -271,10 +272,11 @@ class ServeEngine:
         (asserted by the CI recompile guard).  Returns compile stats.
 
         Plan/scan programs are AOT-compiled (``jit(...).lower(shape)
-        .compile()``) — no trajectory executes, so warmup pays compile
-        time only.  Static mode (and any mode under a mesh, where an
-        AOT executable would pin input shardings) warms by running one
-        trajectory per batch bucket instead."""
+        .compile()``, side by side in the engine's
+        ``parallel_compiles`` pool) — no trajectory executes, so warmup
+        pays compile time only.  Static mode (and any mode under a mesh,
+        where an AOT executable would pin input shardings) warms by
+        running one trajectory per batch bucket instead."""
         n0 = len(self.engine._programs)
         t0 = time.time()
         aot = self.engine.mesh is None and self.mode in ("plan", "scan")
@@ -284,19 +286,21 @@ class ServeEngine:
             # exercises — flush them now so the first real wave is pure
             # execution
             _, _ = jax.random.split(jax.random.PRNGKey(0))
-        for b in self.batch_buckets():
-            keys = self._row_keys([], b)
-            self._init_noise(keys)           # tiny per-bucket key program
-            if not aot:
-                self._sample_bucket(b, keys)
-            elif self.mode == "plan":
-                sample_plan(self.denoiser.call_masked, self.schedule,
-                            (b, self.store.dim), jax.random.PRNGKey(0),
-                            self.plan, clip_value=self.clip_value,
-                            program_cache=self.engine.program,
-                            compile_only=True, jitter=self.engine.jitter)
-            else:
-                self._scan_program((b, self.store.dim), compile_only=True)
+        with self.engine.parallel_compiles():
+            for b in self.batch_buckets():
+                keys = self._row_keys([], b)
+                self._init_noise(keys)       # tiny per-bucket key program
+                if not aot:
+                    self._sample_bucket(b, keys)
+                elif self.mode == "plan":
+                    sample_plan(self.denoiser.call_masked, self.schedule,
+                                (b, self.store.dim), jax.random.PRNGKey(0),
+                                self.plan, clip_value=self.clip_value,
+                                program_cache=self.engine.program,
+                                compile_only=True, jitter=self.engine.jitter)
+                else:
+                    self._scan_program((b, self.store.dim),
+                                       compile_only=True)
         return {"programs_compiled": len(self.engine._programs) - n0,
                 "batch_buckets": self.batch_buckets(),
                 "shape_buckets": (self.plan.num_buckets if self.plan
@@ -351,7 +355,7 @@ class ServeEngine:
         return out
 
 
-def main():
+def main(argv: list[str] | None = None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", default="cifar_like")
     ap.add_argument("--n", type=int, default=2048)
@@ -380,7 +384,8 @@ def main():
     ap.add_argument("--metrics", action="store_true",
                     help="count dispatches/compiles per program kind and "
                          "print a Prometheus text snapshot on exit")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tracer = (obs_trace.Tracer(capacity=1 << 16) if args.trace_out
               else obs_trace.NULL_TRACER)
@@ -408,10 +413,10 @@ def main():
     t0 = time.time()
     results = eng.serve(reqs)
     total = time.time() - t0
-    for r in results:
+    finite = [bool(np.isfinite(r.images).all()) for r in results]
+    for r, ok in zip(results, finite):
         print(f"request {r.request_id}: {r.images.shape} "
-              f"batch-latency={r.latency_s:.2f}s "
-              f"finite={np.isfinite(r.images).all()}")
+              f"batch-latency={r.latency_s:.2f}s finite={ok}")
     n_img = sum(r.images.shape[0] for r in results)
     print(f"served {n_img} images in {total:.2f}s "
           f"({total/max(n_img,1):.3f}s/image, {args.steps} steps)")
@@ -421,6 +426,9 @@ def main():
               f"({tracer.dropped} dropped) -> {args.trace_out}")
     if args.metrics:
         print(obs_metrics.REGISTRY.prometheus(), end="")
+    bad = [r.request_id for r, ok in zip(results, finite) if not ok]
+    if bad:
+        raise SystemExit(f"non-finite images in requests {bad}")
 
 
 if __name__ == "__main__":

@@ -20,7 +20,8 @@ def test_flash_attention_sweep(b, hkv, g, s, dh, qc, kc, causal, dtype):
     q = jax.random.normal(keys[0], (b, hkv, g, s, dh), dtype)
     k = jax.random.normal(keys[1], (b, hkv, s, dh), dtype)
     v = jax.random.normal(keys[2], (b, hkv, s, dh), dtype)
-    out = ops.flash_attention(q, k, v, causal=causal, qc=qc, kc=kc)
+    out = ops.flash_attention(q, k, v, causal=causal, qc=qc, kc=kc,
+                              backend="pallas_interpret")
     expect = ref.flash_attention_ref(q, k, v, causal)
     tol = 2e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -39,7 +40,8 @@ def test_flash_matches_model_layer_attention():
     o_jax = jax_flash(q, k, v, dims, q_chunk=32, kv_chunk=64)
     qg = q.reshape(b, s, hkv, h // hkv, dh).transpose(0, 2, 3, 1, 4)
     o_k = ops.flash_attention(qg, k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), qc=32, kc=64)
+                              v.transpose(0, 2, 1, 3), qc=32, kc=64,
+                              backend="pallas_interpret")
     o_k = o_k.transpose(0, 3, 1, 2, 4).reshape(b, s, h, dh)
     np.testing.assert_allclose(np.asarray(o_k), np.asarray(o_jax),
                                rtol=2e-5, atol=2e-5)

@@ -14,7 +14,7 @@ from repro.kernels.golden_attention import select_golden_blocks
 def test_pdist_sweep(b, n, d, dtype):
     q = jax.random.normal(jax.random.PRNGKey(0), (b, d), dtype)
     x = jax.random.normal(jax.random.PRNGKey(1), (n, d), dtype)
-    out = ops.pdist(q, x)
+    out = ops.pdist(q, x, backend="pallas_interpret")
     expect = ref.pdist_ref(q, x)
     tol = 1e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
@@ -29,7 +29,7 @@ def test_pdist_sweep(b, n, d, dtype):
 def test_golden_aggregate_sweep(b, n, d, sigma2, dtype):
     q = jax.random.normal(jax.random.PRNGKey(2), (b, d), dtype)
     x = jax.random.normal(jax.random.PRNGKey(3), (n, d), dtype)
-    out = ops.golden_aggregate(q, x, sigma2)
+    out = ops.golden_aggregate(q, x, sigma2, backend="pallas_interpret")
     expect = ref.golden_aggregate_ref(q, x, sigma2)
     tol = 2e-4 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -47,7 +47,8 @@ def test_golden_aggregate_matches_optimal_denoiser():
     x = jax.random.normal(jax.random.PRNGKey(4), (4, 16))
     t = 300
     a = float(sch.a[t])
-    out_k = ops.golden_aggregate(x / a, store.X, float(sch.sigma(t)) ** 2)
+    out_k = ops.golden_aggregate(x / a, store.X, float(sch.sigma(t)) ** 2,
+                                 backend="pallas_interpret")
     out_d = den(x, t)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_d),
                                rtol=1e-4, atol=1e-4)
@@ -65,7 +66,8 @@ def test_golden_attention_sweep(b, hkv, g, dh, s, bs, kb, dtype):
     v = jax.random.normal(keys[2], (b, hkv, s, dh), dtype)
     idx, valid = select_golden_blocks(q, k, kb, bs)
     valid = valid.at[:, :, -1].set(0)           # exercise padding mask
-    out = ops.golden_attention_decode(q, k, v, idx, valid, bs)
+    out = ops.golden_attention_decode(q, k, v, idx, valid, bs,
+                                      backend="pallas_interpret")
     expect = ref.golden_attention_decode_ref(q, k, v, idx, valid, bs)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -83,7 +85,8 @@ def test_golden_attention_full_blocks_equals_dense():
     nb = s // bs
     idx = jnp.tile(jnp.arange(nb)[None, None], (b, hkv, 1)).astype(jnp.int32)
     valid = jnp.ones_like(idx)
-    out = ops.golden_attention_decode(q, k, v, idx, valid, bs)
+    out = ops.golden_attention_decode(q, k, v, idx, valid, bs,
+                                      backend="pallas_interpret")
     scores = jnp.einsum("bhgd,bhsd->bhgs", q, k) / dh ** 0.5
     dense = jnp.einsum("bhgs,bhsd->bhgd", jax.nn.softmax(scores, -1), v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
@@ -95,4 +98,5 @@ def test_xla_backend_dispatch():
     x = jax.random.normal(jax.random.PRNGKey(8), (64, 16))
     np.testing.assert_allclose(
         np.asarray(ops.pdist(q, x, backend="xla")),
-        np.asarray(ops.pdist(q, x)), rtol=1e-4, atol=1e-4)
+        np.asarray(ops.pdist(q, x, backend="pallas_interpret")),
+        rtol=1e-4, atol=1e-4)

@@ -171,3 +171,21 @@ print("PASS" if ok and cache_grew == 0 and not compiled else "FAIL")
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=420, cwd=str(REPO), env=env)
     assert "PASS" in r.stdout, r.stdout + r.stderr
+
+
+def test_cli_exits_nonzero_on_nonfinite_images(monkeypatch, capsys):
+    """The serving CLI reports ``finite=False`` per request AND fails:
+    NaN rows injected into every plan segment make ``main`` exit
+    non-zero instead of printing a result and returning 0."""
+    from repro.launch import serve
+    from repro.launch.faults import FaultConfig, injected
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: None)
+    argv = ["--dataset", "gmm", "--n", "256", "--requests", "2",
+            "--batch", "2", "--steps", "3", "--no-warmup"]
+    serve.main(argv)                     # healthy run: returns normally
+    assert "finite=False" not in capsys.readouterr().out
+    with injected(FaultConfig(nan_rate=1.0)):
+        with pytest.raises(SystemExit) as exc:
+            serve.main(argv)
+    assert exc.value.code not in (0, None)
+    assert "finite=False" in capsys.readouterr().out
