@@ -7,9 +7,10 @@ as a standalone compiled program built from the engine's OWN ops
 (``engine.coarse`` / ``engine.coarse_indexed``, ``ops.golden_rerank``,
 ``ops.golden_support_aggregate``, ``ops.golden_aggregate``) on the
 engine's own operands, times it warm, and divides by the *analytic*
-costs from ``repro.core.plan.step_stage_costs`` — the same numbers the
-engine's trace spans carry at serve time (``stage.*`` events), so
-offline roofline cells and online traces speak one cost model.
+costs from ``repro.core.plan.step_stage_costs`` — the cost model the
+chip benchmark's stage rooflines also use (frozen in ``bench/costs.py``
+and divided by the device time of each ``stage.*`` scope), so offline
+roofline cells and chip traces speak one cost model.
 
 Machine peaks are measured in-process the same way: a fat fp32 GEMM for
 peak FLOP/s, a large streaming add for peak byte/s.  The analytic

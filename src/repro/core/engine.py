@@ -153,8 +153,6 @@ import numpy as np
 from jax.sharding import PartitionSpec
 
 from repro.core.dataset import DatasetStore, downsample_proxy
-from repro.core.plan import (full_scan_costs, fused_step_costs,
-                             step_stage_costs)
 from repro.core.schedules import Schedule
 from repro.distributed.sharding import (gather_global_topk, lse_merge_mean,
                                         shard_map_compat)
@@ -388,7 +386,6 @@ class GoldDiffEngine:
         # Per-timestep schedule constants, computed host-side exactly once.
         self._consts: dict[int, tuple[float, float]] = {}
         self._sizes: dict[int, tuple[int, int]] = {}
-        self._stage_costs: dict = {}
         self._programs: dict = {}
         # monotonic build counter: the serving runtime diffs it across a
         # segment dispatch to detect post-warmup compiles (a cache-size
@@ -879,17 +876,22 @@ class GoldDiffEngine:
         m_t, k_t = self.sizes(t)
         if self.use_index(t):
             mp = self.padded_m(t)
-            pos, pd2 = self.coarse_indexed(q, mp, self.nprobe(t))
-            cand = self.index_perm[pos]
-            return ops.golden_rerank(q, self.X, cand, min(k_t, mp),
+            with jax.named_scope("stage.ivf_screen"):
+                pos, pd2 = self.coarse_indexed(q, mp, self.nprobe(t))
+                cand = self.index_perm[pos]
+            with jax.named_scope("stage.rerank"):
+                return ops.golden_rerank(q, self.X, cand, min(k_t, mp),
+                                         x_norms=self.x_norms,
+                                         backend=self.backend,
+                                         strategy="gather",
+                                         valid=jnp.isfinite(pd2))
+        with jax.named_scope("stage.screen"):
+            cand = self.coarse(q, m_t)
+        with jax.named_scope("stage.rerank"):
+            return ops.golden_rerank(q, self.X, cand, k_t,
                                      x_norms=self.x_norms,
                                      backend=self.backend,
-                                     strategy="gather",
-                                     valid=jnp.isfinite(pd2))
-        cand = self.coarse(q, m_t)
-        return ops.golden_rerank(q, self.X, cand, k_t, x_norms=self.x_norms,
-                                 backend=self.backend,
-                                 strategy=self.strategy)
+                                     strategy=self.strategy)
 
     def _select_ids_body(self, q: Array, t: int) -> Array:
         """Golden support as dataset row ids.
@@ -904,11 +906,12 @@ class GoldDiffEngine:
         a, sig2 = self.constants(t)
         q = x_t / a
         idx, d2 = self._select_body(q, t)
-        # +inf distances (capacity-padded slots) clamp to NEG_INF logits
-        lg = jnp.maximum(-d2 / (2.0 * sig2), NEG_INF)
-        out = ops.golden_support_aggregate(self.X, idx, lg,
-                                           backend=self.backend,
-                                           strategy=self.strategy_for(t))
+        with jax.named_scope("stage.aggregate"):
+            # +inf distances (capacity-padded slots) clamp to NEG_INF logits
+            lg = jnp.maximum(-d2 / (2.0 * sig2), NEG_INF)
+            out = ops.golden_support_aggregate(self.X, idx, lg,
+                                               backend=self.backend,
+                                               strategy=self.strategy_for(t))
         return out.astype(x_t.dtype)
 
     def _fused_body(self, x_t: Array, t: int) -> Array:
@@ -919,12 +922,13 @@ class GoldDiffEngine:
         a, sig2 = self.constants(t)
         m_t, k_t = self.sizes(t)
         q = x_t / a
-        out = ops.fused_step(q, self._proxy_query(q), self.X, self.proxy,
-                             m_t, k_t, sig2, x_norms=self.x_norms,
-                             proxy_norms=self.proxy_norms,
-                             backend=self.backend, strategy=self.strategy,
-                             stream=self.use_stream(x_t.shape[0]),
-                             tile=self.screen_tile)
+        with jax.named_scope("stage.fused_step"):
+            out = ops.fused_step(q, self._proxy_query(q), self.X, self.proxy,
+                                 m_t, k_t, sig2, x_norms=self.x_norms,
+                                 proxy_norms=self.proxy_norms,
+                                 backend=self.backend, strategy=self.strategy,
+                                 stream=self.use_stream(x_t.shape[0]),
+                                 tile=self.screen_tile)
         return out.astype(x_t.dtype)
 
     # -- sharded (mesh / shard_map) pipeline ---------------------------------
@@ -1017,22 +1021,26 @@ class GoldDiffEngine:
             q = x_t / a
             qp = self._proxy_query(q)
             if use_ix:
-                cand, pd2 = ops.ivf_screen_local(
-                    qp, offs, cents, cnorms, wr[0], wr[1], p_t,
-                    L.max_cluster, w_cap, L.n_loc, backend=backend)
-                valid = jnp.isfinite(pd2)
+                with jax.named_scope("stage.ivf_screen"):
+                    cand, pd2 = ops.ivf_screen_local(
+                        qp, offs, cents, cnorms, wr[0], wr[1], p_t,
+                        L.max_cluster, w_cap, L.n_loc, backend=backend)
+                    valid = jnp.isfinite(pd2)
             else:
-                cand, valid = local_coarse_exact(
-                    qp, pr, pn, m_cap, m_t, m_t, ax, backend=backend,
-                    stream=self.use_stream(x_t.shape[0], L.n_loc),
-                    tile=self.screen_tile)
-            idx, neg, kth = golden_local_topk(X, xn, q, cand, valid, k_cap,
-                                              k_t, k_t, ax, backend=backend,
-                                              strategy=strategy)
+                with jax.named_scope("stage.screen"):
+                    cand, valid = local_coarse_exact(
+                        qp, pr, pn, m_cap, m_t, m_t, ax, backend=backend,
+                        stream=self.use_stream(x_t.shape[0], L.n_loc),
+                        tile=self.screen_tile)
+            with jax.named_scope("stage.rerank"):
+                idx, neg, kth = golden_local_topk(
+                    X, xn, q, cand, valid, k_cap, k_t, k_t, ax,
+                    backend=backend, strategy=strategy)
             if kind == "select":
                 return gather_global_topk(ids[idx], neg, k_t, ax)
-            out = merged_golden_mean(X, idx, neg, kth, sig2, ax,
-                                     strategy=strategy)
+            with jax.named_scope("stage.aggregate"):
+                out = merged_golden_mean(X, idx, neg, kth, sig2, ax,
+                                         strategy=strategy)
             return out.astype(x_t.dtype)
 
         return self._shard_mapped(local)
@@ -1058,11 +1066,12 @@ class GoldDiffEngine:
              x_t) = self._unpack_local(args)
             q = x_t / a
             qp = self._proxy_query(q)
-            out = fused_local_step(
-                X, xn, q, qp, pr, pn, m_cap, m_t, m_t, k_cap, k_t, k_t,
-                sig2, ax, backend=backend, strategy=strategy,
-                stream=self.use_stream(x_t.shape[0], L.n_loc),
-                tile=self.screen_tile)
+            with jax.named_scope("stage.fused_step"):
+                out = fused_local_step(
+                    X, xn, q, qp, pr, pn, m_cap, m_t, m_t, k_cap, k_t, k_t,
+                    sig2, ax, backend=backend, strategy=strategy,
+                    stream=self.use_stream(x_t.shape[0], L.n_loc),
+                    tile=self.screen_tile)
             return out.astype(x_t.dtype)
 
         return self._shard_mapped(local)
@@ -1111,31 +1120,35 @@ class GoldDiffEngine:
             q = x_t / a
             qp = self._proxy_query(q)
             if fused:
-                out = fused_local_step(
-                    X, xn, q, qp, pr, pn, m_loc, m_cap, m_t, k_loc,
-                    k_cap, k_t, sig * sig, ax, backend=backend,
-                    strategy=strategy,
-                    stream=self.use_stream(x_t.shape[0], L.n_loc),
-                    tile=self.screen_tile)
+                with jax.named_scope("stage.fused_step"):
+                    out = fused_local_step(
+                        X, xn, q, qp, pr, pn, m_loc, m_cap, m_t, k_loc,
+                        k_cap, k_t, sig * sig, ax, backend=backend,
+                        strategy=strategy,
+                        stream=self.use_stream(x_t.shape[0], L.n_loc),
+                        tile=self.screen_tile)
                 return out.astype(x_t.dtype)
             if use_ix:
-                nprobe_t = self._masked_nprobe_t(g, m_t, k_t, p_pad)
-                cand, pd2 = ops.ivf_screen_local(
-                    qp, offs, cents, cnorms, wr[0], wr[1], p_pad,
-                    L.max_cluster, w_cap, L.n_loc, nprobe=nprobe_t,
-                    backend=backend)
-                valid = jnp.isfinite(pd2)
+                with jax.named_scope("stage.ivf_screen"):
+                    nprobe_t = self._masked_nprobe_t(g, m_t, k_t, p_pad)
+                    cand, pd2 = ops.ivf_screen_local(
+                        qp, offs, cents, cnorms, wr[0], wr[1], p_pad,
+                        L.max_cluster, w_cap, L.n_loc, nprobe=nprobe_t,
+                        backend=backend)
+                    valid = jnp.isfinite(pd2)
             else:
-                cand, valid = local_coarse_exact(
-                    qp, pr, pn, m_loc, m_cap, m_t, ax, backend=backend,
-                    stream=self.use_stream(x_t.shape[0], L.n_loc),
-                    tile=self.screen_tile)
-            idx, neg, kth = golden_local_topk(X, xn, q, cand, valid, k_loc,
-                                              k_cap, k_t, ax,
-                                              backend=backend,
-                                              strategy=strategy)
-            out = merged_golden_mean(X, idx, neg, kth, sig * sig, ax,
-                                     strategy=strategy)
+                with jax.named_scope("stage.screen"):
+                    cand, valid = local_coarse_exact(
+                        qp, pr, pn, m_loc, m_cap, m_t, ax, backend=backend,
+                        stream=self.use_stream(x_t.shape[0], L.n_loc),
+                        tile=self.screen_tile)
+            with jax.named_scope("stage.rerank"):
+                idx, neg, kth = golden_local_topk(
+                    X, xn, q, cand, valid, k_loc, k_cap, k_t, ax,
+                    backend=backend, strategy=strategy)
+            with jax.named_scope("stage.aggregate"):
+                out = merged_golden_mean(X, idx, neg, kth, sig * sig, ax,
+                                         strategy=strategy)
             return out.astype(x_t.dtype)
 
         return self._shard_mapped(local, n_extra_rep=1)(
@@ -1160,40 +1173,20 @@ class GoldDiffEngine:
         return self._shard_mapped(local)
 
     # -- observability (spans around host-level dispatches) -------------------
-    def stage_costs(self, kind: str, t: int, batch: int) -> dict:
-        """Cached analytic per-stage FLOPs/bytes (``core.plan``'s
-        accounting) for one entry-point dispatch.  ``select`` drops the
-        aggregate stage (it stops at the golden support)."""
-        key = (kind, int(t), int(batch))
-        if key not in self._stage_costs:
-            if kind == "full_scan":
-                costs = full_scan_costs(self, batch)
-            elif kind == "fused_step":
-                costs = fused_step_costs(self, t, batch)
-            else:
-                costs = step_stage_costs(self, t, batch)
-                if kind == "select":
-                    costs = {s: c for s, c in costs.items()
-                             if s != "aggregate"}
-            self._stage_costs[key] = costs
-        return self._stage_costs[key]
-
     def _traced(self, kind: str, t: int, x_t: Array, fn, compiled: bool):
         """Run ``fn(x_t)`` inside an ``engine.<kind>`` span.
 
         Only reached when the current tracer is enabled (callers branch
         on ``tracer().enabled`` first, so the disabled path stays
-        bit-identical with zero extra work).  Stage point events carry
-        the analytic FLOPs/bytes tags; the dispatch blocks inside the
-        span so the recorded duration is wall-clock, not enqueue time.
+        bit-identical with zero extra work).  The stages inside the
+        program carry ``stage.*`` named scopes, which the device trace
+        reports; the dispatch blocks inside the span so the recorded
+        duration is wall-clock, not enqueue time.
         """
         tr = obs_trace.tracer()
         with tr.span(f"engine.{kind}", t=int(t), backend=self.backend,
                      shape=tuple(x_t.shape), compile=bool(compiled),
                      indexed=bool(self.use_index(t))):
-            for stage, c in self.stage_costs(kind, t, x_t.shape[0]).items():
-                tr.event(f"stage.{stage}", t=int(t), flops=c["flops"],
-                         bytes=c["bytes"])
             out = fn(x_t)
             jax.block_until_ready(out)
         return out
@@ -1333,13 +1326,14 @@ class GoldDiffEngine:
             # fused single-pass masked step: the traced (m_t, k_t)
             # masks enter the fused epilogue (same +inf / NEG_INF
             # semantics as the staged masks below)
-            out = ops.fused_step(
-                q, self._proxy_query(q), self.X, self.proxy,
-                m_cap, min(k_cap, m_cap), sig * sig,
-                x_norms=self.x_norms, proxy_norms=self.proxy_norms,
-                backend=self.backend, strategy=self.strategy,
-                stream=self.use_stream(x_t.shape[0]),
-                tile=self.screen_tile, m_t=m_t, k_t=k_t)
+            with jax.named_scope("stage.fused_step"):
+                out = ops.fused_step(
+                    q, self._proxy_query(q), self.X, self.proxy,
+                    m_cap, min(k_cap, m_cap), sig * sig,
+                    x_norms=self.x_norms, proxy_norms=self.proxy_norms,
+                    backend=self.backend, strategy=self.strategy,
+                    stream=self.use_stream(x_t.shape[0]),
+                    tile=self.screen_tile, m_t=m_t, k_t=k_t)
             return out.astype(x_t.dtype)
         if use_ix:
             # probe width varies with the traced t through the mask; the
@@ -1348,33 +1342,38 @@ class GoldDiffEngine:
             # time-aware candidate budget is nprobe_t, not the m_t mask.
             p_pad = p_cap
             m_pad = p_pad * self.index.max_cluster
-            nprobe_t = self._masked_nprobe_t(g, m_t, k_t, p_pad)
-            pos, pd2 = self.coarse_indexed(q, m_pad, p_pad, nprobe=nprobe_t)
-            cand = self.index_perm[pos]
-            cand_mask = jnp.isfinite(pd2)
+            with jax.named_scope("stage.ivf_screen"):
+                nprobe_t = self._masked_nprobe_t(g, m_t, k_t, p_pad)
+                pos, pd2 = self.coarse_indexed(q, m_pad, p_pad,
+                                               nprobe=nprobe_t)
+                cand = self.index_perm[pos]
+                cand_mask = jnp.isfinite(pd2)
             strategy = "gather"          # dense [B, N] math would void
         else:                            # the index's sublinear coarse
             m_pad = m_cap
-            cand = self.coarse(q, m_pad)                    # top-m sorted
-            cand_mask = jnp.arange(m_pad)[None, :] < m_t
+            with jax.named_scope("stage.screen"):
+                cand = self.coarse(q, m_pad)                # top-m sorted
+                cand_mask = jnp.arange(m_pad)[None, :] < m_t
             strategy = self.strategy
         k_pad = min(k_cap, m_pad)
-        d2 = ops.support_distances(q, self.X, cand, x_norms=self.x_norms,
-                                   backend=self.backend,
-                                   strategy=strategy)
-        d2 = jnp.where(cand_mask, d2, jnp.inf)
-        neg, pos = jax.lax.top_k(-d2, k_pad)
-        idx = jnp.take_along_axis(cand, pos, axis=-1)
-        # selection distances (neg == -d2) reused for the softmax
-        # (k_max <= m_min <= m_t, so in the exact path every selected
-        # candidate is valid; indexed capacity-padded slots carry -inf
-        # and clamp to NEG_INF -> zero weight)
-        lg = jnp.maximum(neg / (2.0 * sig * sig), NEG_INF)
-        k_mask = jnp.arange(k_pad)[None, :] < k_t
-        lg = jnp.where(k_mask, lg, NEG_INF)
-        out = ops.golden_support_aggregate(self.X, idx, lg,
-                                           backend=self.backend,
-                                           strategy=strategy)
+        with jax.named_scope("stage.rerank"):
+            d2 = ops.support_distances(q, self.X, cand, x_norms=self.x_norms,
+                                       backend=self.backend,
+                                       strategy=strategy)
+            d2 = jnp.where(cand_mask, d2, jnp.inf)
+            neg, pos = jax.lax.top_k(-d2, k_pad)
+        with jax.named_scope("stage.aggregate"):
+            idx = jnp.take_along_axis(cand, pos, axis=-1)
+            # selection distances (neg == -d2) reused for the softmax
+            # (k_max <= m_min <= m_t, so in the exact path every selected
+            # candidate is valid; indexed capacity-padded slots carry -inf
+            # and clamp to NEG_INF -> zero weight)
+            lg = jnp.maximum(neg / (2.0 * sig * sig), NEG_INF)
+            k_mask = jnp.arange(k_pad)[None, :] < k_t
+            lg = jnp.where(k_mask, lg, NEG_INF)
+            out = ops.golden_support_aggregate(self.X, idx, lg,
+                                               backend=self.backend,
+                                               strategy=strategy)
         return out.astype(x_t.dtype)
 
     def full_scan(self, x_t: Array, t: int, jit: bool = True) -> Array:

@@ -186,8 +186,9 @@ def step_stage_costs(engine, t: int, batch: int = 1) -> dict:
     Returns ``{stage: {"flops": float, "bytes": float}}`` with stages
     ``screen`` *or* ``ivf_screen`` (by ``engine.use_index(t)``), then
     ``rerank`` and ``aggregate`` — the operand-traffic/arithmetic model
-    the roofline benchmark and the engine's stage spans share.  The
-    conventions (documented so cells stay comparable across PRs):
+    the roofline benchmark and the chip benchmark's stage rooflines
+    (``bench/costs.py``, a frozen copy) share.  The conventions
+    (documented so cells stay comparable across PRs):
 
     * matmul-form distances count 2*rows*dim FLOPs per query (one
       multiply-add per element);

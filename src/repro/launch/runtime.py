@@ -86,8 +86,12 @@ admission / scheduling / failure machinery a service actually needs:
   expiry, wave admission, each segment (a span), retries, splits,
   repacks, Gaussian fallbacks, delivery — lands on the unified event
   schema, so a request's full history is reconstructable from the
-  trace alone.  ``benchmarks/serve_resilience.py`` turns the same
-  numbers into gated BENCH cells.
+  trace alone.  Each ``pump()`` is a ``runtime.pump`` span holding the
+  seam's host steps (``runtime.admit``, ``runtime.noise``,
+  ``wave.segment`` with ``wave.h2d``/``wave.execute``/``wave.d2h``/
+  ``wave.guard``, ``runtime.post``), mirrored into a ``jax.profiler``
+  trace beside the device operations.  ``benchmarks/serve_resilience.py``
+  turns the same numbers into gated BENCH cells.
 
 Single-threaded by design: ``pump()`` runs one scheduler step (admit ->
 pick wave -> run one segment -> postprocess); ``run_until_idle()``
@@ -744,8 +748,7 @@ class ServeRuntime:
             bucket = self.eng._bucket_for(used)
             keys = self.eng._row_keys(
                 [(p.ticket.request, 0, p.n) for p in parts], bucket)
-            x = np.asarray(jax.block_until_ready(
-                self.eng._init_noise(keys)), np.float32)
+            x = self._noise(keys, "wave")
             wave = _Wave(seq=self._seq, mode=mode, plan_name=name,
                          plan=plan, bucket=bucket, x=x, parts=parts,
                          epoch=self.engine.serving_epoch,
@@ -802,8 +805,7 @@ class ServeRuntime:
         for p in joined:
             keys = self.eng._row_keys([(p.ticket.request, 0, p.n)],
                                       self.eng._bucket_for(p.n))
-            rows = np.asarray(jax.block_until_ready(
-                self.eng._init_noise(keys)), np.float32)[: p.n]
+            rows = self._noise(keys, "join")[: p.n]
             wave.x[ofs: ofs + p.n] = rows
             wave.parts.append(p)
             self.counters["joins"] += 1
@@ -813,6 +815,17 @@ class ServeRuntime:
                          rows=p.n, slot=ofs, cursor=0,
                          queue_wait_s=now - p.ticket.submitted_at)
             ofs += p.n
+
+    def _noise(self, keys, kind: str) -> np.ndarray:
+        """Terminal noise for ``keys`` on the host: a device round trip
+        (a ``runtime.noise`` span, ``kind`` "wave" or "join")."""
+        tr = obs_trace.tracer()
+        if not tr.enabled:
+            return np.asarray(jax.block_until_ready(
+                self.eng._init_noise(keys)), np.float32)
+        with tr.span("runtime.noise", kind=kind, rows=int(keys.shape[0])):
+            return np.asarray(jax.block_until_ready(
+                self.eng._init_noise(keys)), np.float32)
 
     def _pick_wave(self, now: float) -> _Wave | None:
         """Earliest-deadline-first over waves, FIFO on ties."""
@@ -941,11 +954,10 @@ class ServeRuntime:
                 if mixed:
                     fn = self._segment_fn(wave, seg, True)
                     self.counters["mixed_segments"] += 1
-                    out = fn(jnp.asarray(x_prev), jnp.asarray(pos))
+                    out = self._execute(fn, (x_prev, pos), tr)
                 else:
                     fn = self._segment_fn(wave, seg, False)
-                    out = fn(jnp.asarray(x_prev))
-                out = np.asarray(jax.block_until_ready(out), np.float32)
+                    out = self._execute(fn, (x_prev,), tr)
                 if self.engine._builds > builds0 and self._warm:
                     # evict-then-rebuild storms recompile without
                     # changing the cache size; the build counter sees
@@ -982,11 +994,32 @@ class ServeRuntime:
                     wave.degraded = True
                     break
                 self._backoff(attempt)
-        # per-row finite guard: never let NaN/inf cross a seam.  Frozen
-        # rows are untouched copies of state that already passed this
-        # guard, so only active rows can trip it (and only active rows
-        # may take the Gaussian replacement — it ran this segment's
-        # span, not theirs).
+        if not tr.enabled:
+            return "ok", self._guard(wave, seg, x_prev, out, act, tr)
+        with tr.span("wave.guard"):
+            return "ok", self._guard(wave, seg, x_prev, out, act, tr)
+
+    @staticmethod
+    def _execute(fn, args: tuple, tr) -> np.ndarray:
+        """``fn`` on the device copies of ``args``, back on the host;
+        traced as ``wave.h2d``, ``wave.execute`` and ``wave.d2h``."""
+        if not tr.enabled:
+            out = fn(*(jnp.asarray(a) for a in args))
+            return np.asarray(jax.block_until_ready(out), np.float32)
+        with tr.span("wave.h2d", bytes=sum(int(a.nbytes) for a in args)):
+            dev = [jnp.asarray(a) for a in args]
+        with tr.span("wave.execute"):
+            out = jax.block_until_ready(fn(*dev))
+        with tr.span("wave.d2h", bytes=int(out.nbytes)):
+            return np.asarray(out, np.float32)
+
+    def _guard(self, wave: _Wave, seg: int, x_prev: np.ndarray,
+               out: np.ndarray, act: np.ndarray, tr) -> np.ndarray:
+        """Per-row finite guard: never let NaN/inf cross a seam.  Frozen
+        rows are untouched copies of state that already passed this
+        guard, so only active rows can trip it (and only active rows
+        may take the Gaussian replacement — it ran this segment's span,
+        not theirs)."""
         used = wave.used
         row_ok = np.isfinite(out[:used]).all(axis=1) | ~act[:used]
         if not row_ok.all():
@@ -1006,7 +1039,7 @@ class ServeRuntime:
         else:
             self.br_screen.record_success(self.cfg.clock())
             self.br_exec.record_success(self.cfg.clock())
-        return "ok", out
+        return out
 
     # -- post-segment bookkeeping (under the lock) ----------------------------
     def _split(self, wave: _Wave) -> None:
@@ -1160,17 +1193,26 @@ class ServeRuntime:
     # -- scheduler loop -------------------------------------------------------
     def pump(self) -> bool:
         """One scheduler step.  Returns True if a segment ran."""
+        tr = obs_trace.tracer()
+        if not tr.enabled:
+            return self._pump(tr)
+        with tr.span("runtime.pump") as sp:
+            ran = self._pump(tr)
+            sp.set(ran=ran)
+            return ran
+
+    def _pump(self, tr) -> bool:
         with self._lock:
             now = self.cfg.clock()
-            self._expire_queued(now)
-            # pre-admission seam: rows already past their deadline are
-            # dropped BEFORE admission, so the slots they free (and the
-            # smaller repacked buckets) are joinable at this very seam
-            for w in list(self._waves):
-                if not w.running:
-                    self._compact_expired(w, now)
-            self._admit(now)
-            wave = self._pick_wave(now)
+            if not tr.enabled:
+                wave = self._seam(now)
+            else:
+                joins0, seq0 = self.counters["joins"], self._seq
+                with tr.span("runtime.admit",
+                             queue_depth=len(self._queue)) as sp:
+                    wave = self._seam(now)
+                    sp.set(joins=self.counters["joins"] - joins0,
+                           waves=self._seq - seq0)
             if wave is None:
                 return False
             seg = self._pick_segment(wave)
@@ -1181,9 +1223,29 @@ class ServeRuntime:
             with self._lock:
                 wave.running = False
         with self._lock:
-            self._post_segment(wave, seg, result)
-            self._gc_epochs()            # waves done on an old epoch may
-        return True                      # have been its last reference
+            if not tr.enabled:
+                self._post(wave, seg, result)
+            else:
+                with tr.span("runtime.post"):
+                    self._post(wave, seg, result)
+        return True
+
+    def _seam(self, now: float) -> _Wave | None:
+        """Expiry, seam compaction and admission; the wave to run next."""
+        self._expire_queued(now)
+        # pre-admission seam: rows already past their deadline are
+        # dropped BEFORE admission, so the slots they free (and the
+        # smaller repacked buckets) are joinable at this very seam
+        for w in list(self._waves):
+            if not w.running:
+                self._compact_expired(w, now)
+        self._admit(now)
+        return self._pick_wave(now)
+
+    def _post(self, wave: _Wave, seg: int, result) -> None:
+        self._post_segment(wave, seg, result)
+        # waves done on an old epoch may have been its last reference
+        self._gc_epochs()
 
     def run_until_idle(self, max_iters: int = 100_000) -> None:
         """Drain the queue and all in-flight waves inline.

@@ -27,26 +27,49 @@ injector emits onto the same stream, see ``repro.launch.faults``):
 
 ``span`` is the owning span's id for begin/end pairs (and the enclosing
 span for points; 0 = top level); ``parent`` is the enclosing span's id.
-``end`` events carry ``tags["dur"]`` (seconds).  The clock is
-injectable (``Tracer(clock=...)``) so span ordering/duration tests run
-deterministically under a fake clock.
+``end`` events carry ``tags["dur"]`` (seconds), plus any tags the span
+set while open (``span.set(...)``: values known only at its close).
+The clock is injectable (``Tracer(clock=...)``) so span
+ordering/duration tests run deterministically under a fake clock.
+
+**Profiler mirror.**  Every span of an enabled tracer also opens a
+``jax.profiler.TraceAnnotation`` of the same name (imported on first
+use), so under ``jax.profiler.start_trace`` the program's spans land on
+the host plane of the device trace, on the profiler's clock, beside the
+device operations.  While an enabled tracer is current, ``set_tracer``
+also installs a ``gc.callbacks`` hook that records every collection as
+a ``gc.collect`` span; installing the null tracer removes it.
 
 Span taxonomy (see README "Observability"):
 
   ``engine.denoise|select|full_scan|fused_step``  one per engine entry
       dispatch (``fused_step`` when the fused="auto" policy routes the
       step through the single-pass fused program)
-  ``stage.screen|ivf_screen|rerank|aggregate|full_scan|fused_step``
-      point events carrying analytic ``flops``/``bytes`` tags
-      (``core.plan``; fused steps emit one whole-step stage event)
+  ``stage.screen|ivf_screen|rerank|aggregate|fused_step``  not host
+      events: ``jax.named_scope``s around each stage inside the
+      compiled step (``core.engine``), so every device operation of the
+      step carries its stage in its ``tf_op`` metadata in a profiler trace
   ``dispatch.<kind>``  one per program-cache dispatch (TraceHook)
   ``plan.segment``     one per trajectory-plan bucket execution
-  ``wave.segment``     one per serving-runtime segment (+ ``wave.*`` /
-      ``request.*`` lifecycle points)
+  ``runtime.pump``     one serving-runtime scheduler step, containing
+      ``runtime.admit`` (expiry, seam compaction, admission, wave pick;
+      tags ``queue_depth`` then ``joins``/``waves``), ``runtime.noise``
+      (a terminal-noise device round trip for a new wave or a join),
+      ``wave.segment`` and ``runtime.post`` (delivery, repacking, epoch
+      collection)
+  ``wave.segment``     one per serving-runtime segment, containing
+      ``wave.h2d`` (host-to-device copy of the wave, ``bytes``),
+      ``wave.execute`` (dispatch through ``block_until_ready``),
+      ``wave.d2h`` (device-to-host copy, ``bytes``) and ``wave.guard``
+      (the per-row finite guard); plus ``wave.*`` / ``request.*``
+      lifecycle points
+  ``gc.collect``       one per Python garbage collection (``generation``,
+      then ``collected``/``uncollectable``)
   ``fault.<kind>``     injected faults, inline (launch.faults)
 """
 from __future__ import annotations
 
+import gc
 import json
 import time
 
@@ -62,21 +85,39 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def set(self, **tags):
+        return None
+
 
 _NULL_SPAN = _NullSpan()
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``name``."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation   # deferred: obs stays light
+        _ANNOTATION = TraceAnnotation
+    ann = _ANNOTATION(name)
+    ann.__enter__()
+    return ann
 
 
 class _Span:
-    """Live span handle: closes with an ``end`` event carrying ``dur``."""
+    """Live span handle: closes with an ``end`` event carrying ``dur``
+    and whatever :meth:`set` added."""
 
-    __slots__ = ("tracer", "name", "sid", "parent", "t0")
+    __slots__ = ("tracer", "name", "sid", "parent", "t0", "ann", "end_tags")
 
-    def __init__(self, tracer, name, sid, parent, t0):
+    def __init__(self, tracer, name, sid, parent, t0, ann):
         self.tracer = tracer
         self.name = name
         self.sid = sid
         self.parent = parent
         self.t0 = t0
+        self.ann = ann
+        self.end_tags = None
 
     def __enter__(self):
         return self
@@ -84,6 +125,13 @@ class _Span:
     def __exit__(self, *exc):
         self.tracer._end_span(self)
         return False
+
+    def set(self, **tags):
+        """Tags for the ``end`` event: values known only at the close."""
+        if self.end_tags is None:
+            self.end_tags = tags
+        else:
+            self.end_tags.update(tags)
 
 
 class Tracer:
@@ -115,18 +163,22 @@ class Tracer:
         parent = self._stack[-1] if self._stack else 0
         sid = self._next_span
         self._next_span += 1
+        ann = _annotation(name)
         t0 = self.clock()
         self._emit("begin", name, sid, parent, tags)
         self._stack.append(sid)
-        return _Span(self, name, sid, parent, t0)
+        return _Span(self, name, sid, parent, t0, ann)
 
     def _end_span(self, s: _Span):
         if self._stack and self._stack[-1] == s.sid:
             self._stack.pop()
         elif s.sid in self._stack:        # tolerate mis-nested exits
             self._stack.remove(s.sid)
-        self._emit("end", s.name, s.sid, s.parent,
-                   {"dur": self.clock() - s.t0})
+        tags = {"dur": self.clock() - s.t0}
+        if s.end_tags:
+            tags.update(s.end_tags)
+        self._emit("end", s.name, s.sid, s.parent, tags)
+        s.ann.__exit__(None, None, None)
 
     def event(self, name: str, **tags):
         """Point event inside the current span (0 = top level)."""
@@ -186,16 +238,38 @@ _TRACER: Tracer = NULL_TRACER
 
 def set_tracer(tr: Tracer | None) -> Tracer:
     """Install ``tr`` (or NULL_TRACER for ``None``) as the process-wide
-    current tracer; returns the previous one so callers can restore."""
+    current tracer; returns the previous one so callers can restore.
+    The ``gc.collect`` hook is installed exactly while the current
+    tracer is enabled."""
     global _TRACER
     prev = _TRACER
     _TRACER = NULL_TRACER if tr is None else tr
+    if _TRACER.enabled:
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
+    elif _gc_hook in gc.callbacks:
+        gc.callbacks.remove(_gc_hook)
     return prev
 
 
 def tracer() -> Tracer:
     """The current tracer (NULL_TRACER when tracing is off)."""
     return _TRACER
+
+
+_GC_SPAN = None
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    """``gc.callbacks`` hook: each collection as a ``gc.collect`` span."""
+    global _GC_SPAN
+    if phase == "start":
+        _GC_SPAN = _TRACER.span("gc.collect", generation=info["generation"])
+    elif _GC_SPAN is not None:
+        _GC_SPAN.set(collected=info["collected"],
+                     uncollectable=info["uncollectable"])
+        _GC_SPAN.__exit__(None, None, None)
+        _GC_SPAN = None
 
 
 class TraceHook:

@@ -11,14 +11,17 @@ The two load-bearing guarantees pinned here:
   still serves with zero post-warmup compiles (probe programs are part
   of warmup's contract).
 """
+import gc
 import json
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from benchmarks.common import merge_bench_json
 from repro.core import GoldDiffEngine, make_schedule
+from repro.core.sampler import plan_segment
 from repro.core.plan import full_scan_costs, step_stage_costs
 from repro.data import gmm
 from repro.index import build_index
@@ -31,6 +34,7 @@ from repro.obs import (NULL_TRACER, MetricsRegistry, QualityMonitor, Tracer,
                        install_dispatch_tracing, set_tracer, tracer,
                        uninstall_dispatch_tracing)
 from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 SCH = make_schedule("ddpm_linear", 1000)
 
@@ -50,6 +54,7 @@ def _no_obs_leak():
     yield
     assert tracer() is NULL_TRACER, "a test leaked an installed tracer"
     assert ops.dispatch_hook() is None, "a test leaked a dispatch hook"
+    assert obs_trace._gc_hook not in gc.callbacks, "a test leaked the gc hook"
 
 
 def _engine(**kw):
@@ -92,6 +97,49 @@ def test_span_nesting_and_durations_under_fake_clock():
     # fake clock ticks once per read: every duration is deterministic
     assert e_in["tags"]["dur"] > 0 and e_out["tags"]["dur"] > 0
     assert e_out["tags"]["dur"] > e_in["tags"]["dur"]
+
+
+def test_span_set_adds_end_tags():
+    tr = Tracer(clock=FakeClock())
+    with tr.span("a", before=1) as sp:
+        sp.set(after=2)
+        sp.set(more=3)
+    begin, end = tr.events()
+    assert begin["tags"] == {"before": 1}
+    assert end["tags"]["after"] == 2 and end["tags"]["more"] == 3
+    assert end["tags"]["dur"] > 0
+    with NULL_TRACER.span("b") as sp:             # the null span takes it
+        sp.set(after=2)
+    assert NULL_TRACER.events() == []
+
+
+def test_gc_hook_only_while_an_enabled_tracer_is_current():
+    hooked = lambda: obs_trace._gc_hook in gc.callbacks  # noqa: E731
+    assert not hooked()
+    tr = Tracer(capacity=1 << 12)
+    prev = set_tracer(tr)
+    try:
+        assert hooked()
+        set_tracer(tr)                            # idempotent
+        assert gc.callbacks.count(obs_trace._gc_hook) == 1
+        gc.collect()
+        set_tracer(NULL_TRACER)                   # a disabled tracer: off
+        assert not hooked()
+        n = len(tr.events())
+        gc.collect()
+        assert len(tr.events()) == n              # nothing recorded while off
+        set_tracer(tr)
+        assert hooked()
+    finally:
+        set_tracer(prev)
+    assert not hooked()
+    spans = [e for e in tr.events() if e["name"] == "gc.collect"]
+    begins = [e for e in spans if e["kind"] == "begin"]
+    ends = [e for e in spans if e["kind"] == "end"]
+    assert any(e["tags"]["generation"] == 2 for e in begins)
+    assert len(begins) == len(ends)
+    assert all({"dur", "collected", "uncollectable"} <= set(e["tags"])
+               for e in ends)
 
 
 def test_ring_buffer_wrap_keeps_latest_and_counts_drops():
@@ -239,8 +287,60 @@ def test_disabled_tracer_is_bit_identical_with_zero_recompiles():
     assert eng._builds == b0, "tracing must not change program cache keys"
     names = {e["name"] for e in tr.events()}
     # fused="auto" (the default) routes these dense-strategy steps
-    # through the single-pass fused program
-    assert "engine.fused_step" in names and "stage.fused_step" in names
+    # through the single-pass fused program; its stage is a named scope
+    # inside the compiled program, not a host event
+    assert "engine.fused_step" in names
+    assert not any(n.startswith("stage.") for n in names)
+    text = jax.jit(lambda v: eng._fused_body(v, 800)).lower(x).as_text(
+        debug_info=True)
+    assert "stage.fused_step/" in text
+
+
+def _scopes(text: str) -> set:
+    return {s for s in ("stage.screen", "stage.ivf_screen", "stage.rerank",
+                        "stage.aggregate", "stage.fused_step")
+            if f"{s}/" in text}
+
+
+def test_served_plan_segment_carries_stage_scopes():
+    """The lowered plan segment the runtime serves names every stage in
+    its op metadata: a device trace attributes each operation to one."""
+    eng = ServeEngine("gmm", {"n": 256, "dim": 8}, num_steps=4,
+                      max_batch=2, fused=False)
+    plan = ServeRuntime(eng).plans["primary"]
+    for b in plan.buckets:
+        seg = plan_segment(eng.denoiser.call_masked, eng.schedule, plan, b,
+                           eng.clip_value)
+        text = jax.jit(seg).lower(
+            jax.ShapeDtypeStruct((2, 8), jnp.float32)).as_text(
+                debug_info=True)
+        assert _scopes(text) == {"stage.screen", "stage.rerank",
+                                 "stage.aggregate"}
+
+
+@pytest.mark.parametrize("path,want", [
+    ("masked_exact", {"stage.screen", "stage.rerank", "stage.aggregate"}),
+    ("masked_indexed", {"stage.ivf_screen", "stage.rerank",
+                        "stage.aggregate"}),
+    ("masked_fused", {"stage.fused_step"}),
+    ("static_exact", {"stage.screen", "stage.rerank", "stage.aggregate"}),
+    ("static_indexed", {"stage.ivf_screen", "stage.rerank",
+                        "stage.aggregate"}),
+    ("select_exact", {"stage.screen", "stage.rerank"}),
+])
+def test_every_step_path_labels_its_stages(path, want):
+    ix = build_index(gmm(256, dim=8, seed=0), num_clusters=8)
+    eng = _engine(fused=path.endswith("fused"),
+                  **({"index": ix, "index_mode": "always"}
+                     if "indexed" in path else {}))
+    x = jax.ShapeDtypeStruct((2, 8), jnp.float32)
+    if path.startswith("masked"):
+        fn = lambda v: eng.denoise_masked(v, jnp.int32(400))  # noqa: E731
+    elif path.startswith("static"):
+        fn = lambda v: eng._denoise_body(v, 400)              # noqa: E731
+    else:
+        fn = lambda v: eng._select_ids_body(v, 400)           # noqa: E731
+    assert _scopes(jax.jit(fn).lower(x).as_text(debug_info=True)) == want
 
 
 def test_fault_events_land_on_the_trace_stream():
@@ -390,6 +490,55 @@ def test_request_lifecycle_reconstructable_from_trace(serve_eng, obs_rt):
                          for e in waves)
     # lifecycle ordering: admit precedes the first segment precedes deliver
     assert admits[0]["seq"] < waves[0]["seq"] < delivers[-1]["seq"]
+
+
+def _span_tree(events):
+    """{span id: (name, parent id)} of the closed spans, and the end
+    events' ``dur`` and tags by span id."""
+    begins = {e["span"]: e for e in events if e["kind"] == "begin"}
+    ends = {e["span"]: e for e in events if e["kind"] == "end"}
+    tree = {sid: (b["name"], b["parent"]) for sid, b in begins.items()
+            if sid in ends}
+    return tree, ends
+
+
+def test_runtime_pump_spans_nest_under_a_fake_clock(serve_eng, obs_rt):
+    tr = Tracer(capacity=1 << 14, clock=FakeClock())
+    prev = set_tracer(tr)
+    try:
+        tk = [obs_rt.submit(Request(20 + i, 2, seed=70 + i))
+              for i in range(3)]
+        obs_rt.run_until_idle()
+    finally:
+        set_tracer(prev)
+    assert all(t.status == "done" for t in tk)
+    tree, ends = _span_tree(tr.events())
+    kids: dict = {}
+    for sid, (name, parent) in sorted(tree.items()):
+        kids.setdefault(parent, []).append(name)
+    pumps = [sid for sid, (n, _) in tree.items() if n == "runtime.pump"]
+    ran = [sid for sid in pumps if ends[sid]["tags"]["ran"]]
+    assert ran and all(tree[sid][1] == 0 for sid in pumps)
+    for sid in ran:
+        names = [n for n in kids[sid] if not n.startswith(("gc.", "dispatch"))]
+        assert names == ["runtime.admit", "wave.segment", "runtime.post"]
+    segs = [sid for sid, (n, _) in tree.items() if n == "wave.segment"]
+    assert len(segs) == len(ran)
+    for sid in segs:
+        names = [n for n in kids[sid] if not n.startswith(("gc.", "dispatch"))]
+        assert names == ["wave.h2d", "wave.execute", "wave.d2h",
+                         "wave.guard"]
+    # noise round trips sit inside admission: a new wave, or a join
+    noise = [sid for sid, (n, _) in tree.items() if n == "runtime.noise"]
+    assert noise and all(tree[tree[sid][1]][0] == "runtime.admit"
+                         for sid in noise)
+    admits = [ends[sid]["tags"] for sid, (n, _) in tree.items()
+              if n == "runtime.admit"]
+    assert sum(a["waves"] + a["joins"] for a in admits) == len(noise)
+    copies = [ends[sid] for sid, (n, _) in tree.items()
+              if n in ("wave.h2d", "wave.d2h")]
+    begins = {e["span"]: e for e in tr.events() if e["kind"] == "begin"}
+    assert all(begins[e["span"]]["tags"]["bytes"] > 0 for e in copies)
 
 
 def test_traced_serving_is_bit_identical_to_untraced(serve_eng, obs_rt):
