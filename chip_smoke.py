@@ -52,6 +52,8 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.kernels.common import gather_rows  # noqa: E402
+
 CIFAR10_N = 50_000
 STEPS = 10
 MAX_BATCH = 8
@@ -85,7 +87,8 @@ def _noisy(store, schedule, t: int, seed: int, batch: int = MAX_BATCH):
     rows = np.random.default_rng(seed).choice(store.n, batch, replace=False)
     eps = jax.random.normal(jax.random.PRNGKey(seed + t),
                             (batch, store.dim), jnp.float32)
-    return float(schedule.a[t]) * store.X[rows] + float(schedule.b[t]) * eps
+    return (float(schedule.a[t]) * gather_rows(store.rows, rows)
+            + float(schedule.b[t]) * eps)
 
 
 def _overlap(a, b) -> float:
@@ -290,8 +293,8 @@ def main(argv=None) -> int:
     n = args.n or CIFAR10_N
     t0 = time.perf_counter()
     store = make_dataset("cifar_like", n=n, seed=args.seed)
-    jax.block_until_ready(store.X)
-    print(f"store: cifar_like {store.X.shape} {store.X.dtype} built in "
+    jax.block_until_ready(store.rows)
+    print(f"store: cifar_like {store.rows.shape} {store.rows.dtype} built in "
           f"{time.perf_counter() - t0:.1f} s", flush=True)
 
     (one_chip if args.chips == 1 else four_chips)(checks, store, args.seed)

@@ -30,6 +30,7 @@ from repro.core import streaming
 from repro.core.dataset import DatasetStore, pairwise_sq_dists
 from repro.core.schedules import Schedule
 from repro.kernels import ops
+from repro.kernels.common import gather_rows
 
 Array = jnp.ndarray
 Weighting = Literal["ss", "wss"]
@@ -68,18 +69,20 @@ class OptimalDenoiser:
         a = float(self.schedule.a[t])
         sig2 = float(self.schedule.sigma_np(t)) ** 2
         q = x_t / a
-        d2 = pairwise_sq_dists(q, self.store.X, self.store.x_norms)
+        d2 = pairwise_sq_dists(q, self.store.rows, self.store.x_norms)
         return -d2 / (2.0 * sig2)
 
     def __call__(self, x_t: Array, t: int, support: Array | None = None) -> Array:
         if support is not None:
             return self._on_support(x_t, t, support)
         if self.weighting == "wss":
+            # the WSS baseline's chunk einsums take [N, D] values: on a
+            # TPU this squeeze copies the store (not a serving path)
             return streaming.weighted_streaming_softmax_mean(
-                self.logits(x_t, t), self.store.X, self.chunk)
+                self.logits(x_t, t), self.store.rows[:, 0], self.chunk)
         a = float(self.schedule.a[t])
         sig2 = float(self.schedule.sigma_np(t)) ** 2
-        return ops.golden_aggregate(x_t / a, self.store.X, sig2,
+        return ops.golden_aggregate(x_t / a, self.store.rows, sig2,
                                     x_norms=self.store.x_norms,
                                     backend=self.backend).astype(x_t.dtype)
 
@@ -88,16 +91,17 @@ class OptimalDenoiser:
         a = float(self.schedule.a[t])
         sig2 = float(self.schedule.sigma_np(t)) ** 2
         q = x_t / a                                # [B, D]
-        d2 = ops.support_distances(q, self.store.X, idx,
+        d2 = ops.support_distances(q, self.store.rows, idx,
                                    x_norms=self.store.x_norms,
                                    backend=self.backend)
         lg = -d2 / (2.0 * sig2)
         if mask is not None:
             lg = jnp.where(mask, lg, streaming.NEG_INF)
         if self.weighting == "wss":
-            return streaming.wss_combine(lg, self.store.X[idx])
+            return streaming.wss_combine(lg, gather_rows(self.store.rows, idx))
         return ops.golden_support_aggregate(
-            self.store.X, idx, lg, backend=self.backend).astype(x_t.dtype)
+            self.store.rows, idx, lg, backend=self.backend
+        ).astype(x_t.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -222,7 +226,7 @@ class PatchDenoiser:
         chunk = min(self.chunk, n)
         for s in range(0, n, chunk):
             e = min(s + chunk, n)
-            ximg = self._imgs(self.store.X[s:e])
+            ximg = self._imgs(self.store.rows[s:e, 0])
             xf = self._chunk_features(s, e, ximg, patch)
             dist = self.feature_dist(qf, xf, patch)             # [B,nc,H,W]
             lg = (-dist / (2.0 * sig2)).reshape(b, e - s, -1)
@@ -237,7 +241,7 @@ class PatchDenoiser:
         bsz = q.shape[0]
 
         def one(qi, qfi, ids, mi):
-            ximg = self._imgs(self.store.X[ids])                 # [k,H,W,C]
+            ximg = self._imgs(gather_rows(self.store.rows, ids))  # [k,H,W,C]
             xf = self.features(ximg, patch)
             dist = self.feature_dist(qfi[None], xf, patch)[0]    # [k,H,W]
             lg = -dist / (2.0 * sig2)
@@ -289,7 +293,7 @@ class PCADenoiser(PatchDenoiser):
         """
         key = ("feat", patch)
         if key not in self._bases:
-            imgs = self._imgs(self.store.X)
+            imgs = self._imgs(self.store.rows[:, 0])   # a relayout anyway
             chunks = []
             step = max(1, 4096 // max(self.h // 8, 1))
             for s in range(0, self.store.n, step):
@@ -307,7 +311,7 @@ class PCADenoiser(PatchDenoiser):
             lg = -dist / (2.0 * sig2)
             if mi is not None:
                 lg = jnp.where(mi[:, None, None], lg, streaming.NEG_INF)
-            ximg = self._imgs(self.store.X[ids])
+            ximg = self._imgs(gather_rows(self.store.rows, ids))
             if self.weighting == "wss":
                 k = lg.shape[0]
                 lgp = jnp.moveaxis(lg.reshape(k, -1), 0, -1)
@@ -326,7 +330,7 @@ class PCADenoiser(PatchDenoiser):
         if patch in self._bases:
             return self._bases[patch]
         rng = np.random.default_rng(self.seed + patch)
-        x = np.asarray(self.store.X).reshape(-1, self.h, self.w, self.c)
+        x = self.store.X.reshape(-1, self.h, self.w, self.c)
         n = x.shape[0]
         cnt = min(self.num_fit_patches, 16384)
         ii = rng.integers(0, n, cnt)

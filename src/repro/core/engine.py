@@ -41,12 +41,12 @@ Engine features:
 
 Backend/strategy matrix::
 
-    backend           screening distances     aggregation
-    ----------------  ----------------------  --------------------------
-    xla + dense       dense GEMM + lookup     scatter + GEMM
-    xla + gather      row gather + einsum     row gather + einsum
-    pallas_interpret  gather + tiled kernel   gather + streaming kernel
-    pallas            gather + tiled kernel   gather + streaming kernel
+    backend           re-rank distances        aggregation
+    ----------------  -----------------------  --------------------------
+    xla + dense       dense GEMM + lookup      scatter + GEMM
+    xla + gather      row gather + einsum      row gather + einsum
+    pallas_interpret  row-fetch tiled kernel   row-fetch streaming kernel
+    pallas            row-fetch tiled kernel   row-fetch streaming kernel
 
 On the pallas backends the streamed screen and the fused candidate pass
 run as ``lax.scan`` programs (Mosaic cannot lower their top-m merge);
@@ -58,7 +58,7 @@ so dense wins whenever the touched rows are a sizable fraction of N,
 but the gather form wins below the platform's crossover fraction
 (``GATHER_CROSSOVER_FRAC``, measured ~10% of N on CPU; pass
 ``strategy="measure"`` to probe the live device instead of using the
-table).  On TPU the tiled VMEM kernels always gather.
+table).  On TPU the row-fetch kernels always read only the candidates.
 
 **Streamed exact screening** (``screen=``): the exact coarse stage and
 the full scan route through ``ops.screen_topm`` / the streaming LSE
@@ -160,6 +160,7 @@ from repro.index.schedule import ProbeSchedule
 from repro.index.shard import shard_layout
 from repro.index.store import GoldenIndex
 from repro.kernels import ops, ref
+from repro.kernels.common import gather_rows
 from repro.obs import trace as obs_trace
 
 Array = jnp.ndarray
@@ -206,9 +207,21 @@ class StoreOperands(NamedTuple):
 
     Index fields are ``None`` on unindexed engines (None is empty pytree
     structure, so indexed/unindexed programs cannot collide).
+
+    Store layout (the rule is stated in ``kernels/common.py``): ``X`` is
+    the store's own ``[N, 1, D]`` rows (``DatasetStore.rows``, cast to
+    the storage dtype here, once), on every backend.  In fp32 the Pallas
+    re-rank and support-aggregate kernels DMA single candidate rows
+    straight out of it (``kernels/common.fetch_tile``) and the full-scan
+    kernel tiles it in ``(rows, 1, D)`` blocks; a 16-bit store, which
+    XLA lays out as ``[N, D]`` tiles, is read through that bitcast view
+    and an XLA row gather.  XLA math contracts the rows' last axis and
+    squeezes gathered rows.  The store is never reshaped inside a step
+    program (on a TPU that copies all of it), so the device holds one
+    copy of the rows.
     """
 
-    X: Array                        # [N, D] dataset rows (storage dtype)
+    X: Array                        # [N, 1, D] dataset rows (storage dtype)
     proxy: Array                    # [N, dp] proxy rows (storage dtype)
     x_norms: Array                  # [N] fp32 ||x||^2
     proxy_norms: Array              # [N] fp32 ||proxy||^2
@@ -228,16 +241,17 @@ def measure_crossover(x: Array, x_norms: Array, batch: int = 8,
     einsum form for ``rows`` touched rows, and extrapolates the touched
     fraction at which they break even (gather cost is ~linear in rows,
     dense cost ~constant).  A coarse estimate is fine here: it only
-    picks a strategy, both of which are exact.
+    picks a strategy, both of which are exact.  ``x``: the store rows
+    ``[N, 1, D]``.
     """
     n = x.shape[0]
     rows = min(rows, n)
-    q = jnp.zeros((batch, x.shape[1]), x.dtype)
+    q = jnp.zeros((batch, x.shape[-1]), x.dtype)
     idx = jnp.tile((jnp.arange(rows) * 997) % n, (batch, 1))
     dense = jax.jit(lambda q, i: jnp.take_along_axis(
         ref.pdist_ref(q, x, x_norms=x_norms), i, -1))
     gather = jax.jit(lambda q, i: ref.support_sqdist_ref(
-        q, x[i], x_norms[i]))
+        q, gather_rows(x, i), x_norms[i]))
 
     def best(fn):
         jax.block_until_ready(fn(q, idx))
@@ -356,7 +370,7 @@ class GoldDiffEngine:
         platform = jax.default_backend()
         self._screen_budget = SCREEN_MATERIALIZE_BYTES.get(platform, 1 << 31)
         if strategy == "measure":
-            self.crossover_frac = measure_crossover(self.X, self.x_norms)
+            self.crossover_frac = measure_crossover(store.rows, self.x_norms)
         else:
             self.crossover_frac = GATHER_CROSSOVER_FRAC.get(platform, 0.10)
         if strategy in ("gather", "dense"):
@@ -405,10 +419,10 @@ class GoldDiffEngine:
         even under bf16).  Only the PROXY side lives in cluster-sorted
         order (the index already materializes it); X is addressed
         through ``perm`` — one [B, R] int gather — instead of
-        duplicating the whole [N, D] store in sorted order.
+        duplicating the whole store in sorted order.
         """
         sd = self.storage_dtype
-        X, proxy = store.X, store.proxy
+        X, proxy = store.rows, store.proxy
         if sd is not None and X.dtype != sd:
             X = X.astype(sd)
             proxy = proxy.astype(sd)

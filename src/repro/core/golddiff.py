@@ -79,7 +79,8 @@ def golden_select(store: DatasetStore, q: Array, cand: Array, k: int,
     Matmul-form distances via ``ops.golden_rerank`` — no [B, m, D]
     broadcast-subtract temporaries.
     """
-    idx, _ = ops.golden_rerank(q, store.X, cand, k, x_norms=store.x_norms,
+    idx, _ = ops.golden_rerank(q, store.rows, cand, k,
+                               x_norms=store.x_norms,
                                backend=backend)
     return idx
 

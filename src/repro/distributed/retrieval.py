@@ -62,7 +62,7 @@ def shard_store(store: DatasetStore, mesh: Mesh, axis: str = "data"
         return jnp.pad(x, cfg, constant_values=fill)
     sh = NamedSharding(mesh, P(axis))
     return DatasetStore(
-        X=jax.device_put(pad_rows(store.X), sh),
+        rows=jax.device_put(pad_rows(store.rows), sh),
         proxy=jax.device_put(pad_rows(store.proxy), sh),
         # +inf norms on padded rows exclude them from every top-k
         x_norms=jax.device_put(pad_rows(store.x_norms, jnp.inf), sh),
@@ -239,7 +239,7 @@ def distributed_golden_denoise(store: DatasetStore, mesh: Mesh, q: Array,
         return mapped(index.X, index.x_norms, index.offsets, index.wrange,
                       index.ids, q, index.centroids, index.centroid_norms)
 
-    n_loc = store.X.shape[0] // n_sh
+    n_loc = store.n // n_sh
     m_cap = min(m, n_loc)
     k_cap = max(1, min(k, m_cap))
 
@@ -255,4 +255,5 @@ def distributed_golden_denoise(store: DatasetStore, mesh: Mesh, q: Array,
     sp = P(axis)
     mapped = shard_map_compat(local, mesh, in_specs=(sp, sp, sp, sp, P()),
                               out_specs=P())
-    return mapped(store.X, store.x_norms, store.proxy, store.proxy_norms, q)
+    return mapped(store.rows, store.x_norms, store.proxy, store.proxy_norms,
+                  q)

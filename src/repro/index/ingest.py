@@ -575,7 +575,8 @@ class StoreLifecycle:
 
         from repro.core.dataset import DatasetStore
         store = DatasetStore(
-            X=jnp.array(self._X), proxy=jnp.array(self._proxy),
+            rows=jnp.array(self._X[:, None, :]),
+            proxy=jnp.array(self._proxy),
             x_norms=jnp.array(self._xn),
             proxy_norms=jnp.array(self._pn),
             image_shape=self.image_shape, labels=None)

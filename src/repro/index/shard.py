@@ -10,7 +10,7 @@ of the single-host pipeline rather than an approximation:
 * **indexed mode**: the global index's cluster-sorted rows are
   partitioned at CSR *window* boundaries, balanced by row count.  Each
   shard holds the contiguous window-id range ``wrange = [w_lo, w_hi)``,
-  those windows' rows (proxy AND the [n_loc, D] store rows, both in
+  those windows' rows (proxy AND the [n_loc, 1, D] store rows, both in
   cluster-sorted order), and window offsets rebased to shard-local row
   positions.  The (small) centroid table is replicated so every shard
   can run the identical global probe selection
@@ -43,7 +43,7 @@ Array = jnp.ndarray
 class ShardedLayout(NamedTuple):
     """Stacked per-shard golden store (+ optional index routing)."""
 
-    X: Array                   # [S, n_loc, D] store rows (sorted if indexed)
+    X: Array                   # [S, n_loc, 1, D] store rows (sorted if indexed)
     x_norms: Array             # [S, n_loc] fp32 (+inf on padding)
     proxy: Array               # [S, n_loc, dp] (cluster-sorted if indexed)
     proxy_norms: Array         # [S, n_loc] fp32 (+inf on padding)
@@ -86,7 +86,7 @@ def shard_layout(store: DatasetStore, mesh: Mesh, axis: str = "data",
     """Build the stacked per-shard layout (host-side, at engine build)."""
     n_sh = int(mesh.shape[axis])
     n = store.n
-    X = np.asarray(store.X)
+    X = store.X
     proxy = np.asarray(store.proxy)
     xn = np.asarray(store.x_norms, np.float32)
     pn = np.asarray(store.proxy_norms, np.float32)
@@ -125,7 +125,7 @@ def shard_layout(store: DatasetStore, mesh: Mesh, axis: str = "data",
         rows = order[row_cuts[s]: row_cuts[s + 1]]
         ids[s, : len(rows)] = rows
 
-    Xs, ps = stack_rows(X), stack_rows(proxy)
+    Xs, ps = stack_rows(X)[:, :, None, :], stack_rows(proxy)
     if storage_dtype is not None:
         Xs = Xs.astype(storage_dtype)
         ps = ps.astype(storage_dtype)

@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.common import dot_f32
+from repro.kernels.common import dot_rows, gather_rows, row_sq_norms
 from repro.kernels.golden_support_aggregate import (
     golden_support_aggregate as _sagg)
 
@@ -82,8 +82,7 @@ def _merge_topm_carry(vals, idx, ex, neg_tile, idx_tile, ex_tile, m: int):
 
 def _tile_d2(q, xt, qn, xnt):
     """Clamped matmul-form squared distances for one tile (fp32)."""
-    return jnp.maximum(qn + xnt[None, :] - 2.0 * dot_f32(q, xt, ((1,), (1,))),
-                       0.0)
+    return jnp.maximum(qn + xnt[None, :] - 2.0 * dot_rows(q, xt), 0.0)
 
 
 # -- lax.scan candidate pass -------------------------------------------------
@@ -97,7 +96,8 @@ def fused_candidates_scan(qp: jnp.ndarray, q: jnp.ndarray,
     """One-pass screened candidates with exact distances attached.
 
     qp: [B, dp] proxy queries, q: [B, D] exact queries; proxy: [N, dp],
-    x: [N, D] -> ``(idx, d2)`` [B, m]: the proxy top-m candidate list
+    x: [N, 1, D] (the store rows; each tile is contracted on its last
+    axis, not reshaped) -> ``(idx, d2)`` [B, m]: the proxy top-m candidate list
     (ascending proxy distance, ``lax.top_k`` tie order) with each
     slot's EXACT squared distance.  Surplus slots (m > N) carry
     ``d2 = +inf`` and clamped indices.
@@ -120,7 +120,7 @@ def fused_candidates_scan(qp: jnp.ndarray, q: jnp.ndarray,
     if proxy_norms is None:
         proxy_norms = jnp.sum(proxy.astype(jnp.float32) ** 2, -1)
     if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
+        x_norms = row_sq_norms(x)
     proxy_norms = proxy_norms.astype(jnp.float32)
     x_norms = x_norms.astype(jnp.float32)
     tile = min(tile, max(n, 1))
@@ -186,5 +186,5 @@ def fused_posterior(x: jnp.ndarray, idx: jnp.ndarray, d2: jnp.ndarray,
     if backend == "xla":
         if (strategy or "gather") == "dense":
             return ref.scatter_aggregate_ref(x, gid, lg)
-        return ref.golden_support_aggregate_ref(x[gid], lg)
+        return ref.golden_support_aggregate_ref(gather_rows(x, gid), lg)
     return _sagg(x, gid, lg, interpret=interpret)

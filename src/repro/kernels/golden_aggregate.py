@@ -19,14 +19,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
-from repro.kernels.common import VMEM_LIMIT_BYTES, dot_f32, row_tile
+from repro.kernels.common import (VMEM_LIMIT_BYTES, check_rows, dot_f32,
+                                  row_dma, row_sq_norms, row_tile,
+                                  tiled_rows)
 
 NEG_INF = -1e30
 DEFAULT_BQ = 8
 
 
 def _agg_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref,
-                m_ref, l_ref, acc_ref, *, inv_two_sigma2: float, nn: int):
+                m_ref, l_ref, acc_ref, *, inv_two_sigma2: float, nn: int,
+                n: int):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -35,7 +38,13 @@ def _agg_kernel(q_ref, x_ref, qn_ref, xn_ref, out_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]
+    bn = x_ref.shape[0]
+    x = x_ref[...].reshape(bn, x_ref.shape[-1])
+    if n % bn:
+        # the last block runs past the store: zero its rows beyond N
+        # (their +inf norms already zero their weights)
+        row = j * bn + jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)
+        x = jnp.where(row < n, x, jnp.zeros_like(x))
     dot = dot_f32(q_ref[...], x, ((1,), (1,)))
     d2 = qn_ref[...] + xn_ref[...] - 2.0 * dot          # [bq, bn]
     # real rows clamp at the finite NEG_INF floor (extreme sigma -> a
@@ -64,17 +73,23 @@ def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
                      x_norms: jnp.ndarray | None = None,
                      bq: int = DEFAULT_BQ, bn: int | None = None,
                      interpret: bool = False) -> jnp.ndarray:
-    """Full-scan empirical-Bayes posterior mean.  q: [B, D], x: [N, D] -> [B, D].
+    """Full-scan empirical-Bayes posterior mean.  q: [B, D], x: [N, 1, D]
+    (the store rows) -> [B, D].
 
     ``q`` must already be the rescaled query ``x_t / a_t``; ``sigma2`` is the
     noise-to-signal ratio sigma_t^2 (static: one program per timestep, the
     per-step-jit execution mode of DESIGN §3).  ``bn=None`` sizes the
-    store tile to scoped VMEM (``common.row_tile``).
+    store tile to scoped VMEM (``common.row_tile``).  A 32-bit store is
+    read in ``(bn, 1, D)`` blocks of its rows, a 16-bit one in
+    ``(bn, D)`` blocks of its ``[N, D]`` tiles (``common.tiled_rows``).
     """
+    check_rows(x)
     b, d = q.shape
     n = x.shape[0]
     if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
+        x_norms = row_sq_norms(x)
+    if not row_dma(x):
+        x = tiled_rows(x)
     q_norms = jnp.sum(q.astype(jnp.float32) ** 2, -1)
 
     bq = min(bq, b)
@@ -82,7 +97,6 @@ def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
     pb = (-b) % bq
     pn = (-n) % bn
     qp = jnp.pad(q, ((0, pb), (0, 0)))
-    xp = jnp.pad(x, ((0, pn), (0, 0)))
     qn = jnp.pad(q_norms, (0, pb)).reshape(-1, 1)
     # +inf norm on padded rows -> -inf logits -> zero weight
     xn = jnp.pad(x_norms, (0, pn), constant_values=jnp.inf).reshape(1, -1)
@@ -91,11 +105,12 @@ def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
     out = pl.pallas_call(
         functools.partial(_agg_kernel,
                           inv_two_sigma2=ref.finite_inv_two_sigma2(sigma2),
-                          nn=nn),
+                          nn=nn, n=n),
         grid=(nb, nn),
         in_specs=[
             pl.BlockSpec((bq, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((bn, d), lambda i, j: (j, 0)),
+            pl.BlockSpec((bn,) + x.shape[1:],
+                         lambda i, j: (j,) + (0,) * (x.ndim - 1)),
             pl.BlockSpec((bq, 1), lambda i, j: (i, 0)),
             pl.BlockSpec((1, bn), lambda i, j: (0, j)),
         ],
@@ -110,5 +125,5 @@ def golden_aggregate(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(qp, xp, qn, xn)
+    )(qp, x, qn, xn)
     return out[:b]

@@ -38,11 +38,16 @@ GPU/TPU), which is exactly the regime the Golden Index creates, so
 ``support_distances`` / ``golden_support_aggregate`` accept an explicit
 ``strategy`` ("dense" | "gather") that ``GoldDiffEngine`` selects per
 platform at build time instead of hard-coding by backend.  The Pallas
-backends always use the tiled gather kernels, the right shape for TPU
-(MXU matmuls over VMEM tiles).  All paths compute the same math with
-fp32 accumulation at ``HIGHEST`` matmul precision
-(``kernels.common``); parity is asserted in ``tests/test_engine.py`` /
-``tests/test_index.py``.
+backends always use the row-fetch kernels, the right shape for TPU: each
+candidate row is DMA'd from the ``[N, 1, D]`` store into a VMEM tile
+(``kernels.common.fetch_tile``).  Store operands are the rows
+``[N, 1, D]`` (``kernels.common`` states the layout rule; ``[N, D]`` is
+refused, not reshaped); only ``support_distances`` / ``golden_rerank``
+also take a 2-D table, the proxy of the indexed screen.  XLA forms
+contract the rows' last axis and squeeze gathered rows.  All paths
+compute the same math with fp32 accumulation (``HIGHEST`` matmul
+precision or fp32 VPU sums, ``kernels.common``); parity is asserted in
+``tests/test_engine.py`` / ``tests/test_index.py``.
 
 ``ivf_screen`` + ``centroid_scan`` are the indexed (sublinear) coarse
 stage over a ``repro.index.GoldenIndex`` layout.
@@ -54,7 +59,8 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.centroid_scan import centroid_scan as _cscan
-from repro.kernels.common import dot_f32
+from repro.kernels.common import (check_rows, gather_rows, row_sq_norms,
+                                  weigh_rows)
 from repro.kernels.flash_attention import flash_attention as _flash
 from repro.kernels.fused_step import fused_candidates_scan, fused_posterior
 from repro.kernels.golden_aggregate import golden_aggregate as _agg
@@ -163,17 +169,19 @@ def support_distances(q, x, idx, x_norms=None,
     "dense" (one [B, N] GEMM + scalar lookup — no row gathers) or
     "gather" ([B, m, D] row gather + matmul-form distances, sublinear in
     N).  ``None`` keeps the historical per-backend default ("dense" on
-    xla).  The pallas backends always gather — tiled VMEM kernels are
-    the TPU shape regardless.
+    xla).  The pallas backends always take the row-fetch kernel, the
+    TPU shape regardless.  ``x`` is the store rows ``[N, 1, D]`` or a
+    2-D table (the proxy of the indexed screen), whose rows the kernel
+    reads through an XLA gather.
     """
     backend = _resolve(backend)
     if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
+        x_norms = row_sq_norms(x)
     if backend == "xla":
         if (strategy or "dense") == "dense":
             d2_all = ref.pdist_ref(q, x, x_norms=x_norms)
             return jnp.take_along_axis(d2_all, idx, axis=-1)
-        return ref.support_sqdist_ref(q, x[idx], x_norms[idx])
+        return ref.support_sqdist_ref(q, gather_rows(x, idx), x_norms[idx])
     return _sqd(q, x, idx, x_norms,
                 interpret=(backend == "pallas_interpret"), **kw)
 
@@ -231,6 +239,7 @@ def fused_step(q, qp, x, proxy, m: int, k: int, sigma2,
     bit-identical, see the kernel module docstring).
     """
     backend = _resolve(backend)
+    check_rows(x)
     if stream:
         idx, d2 = fused_candidates_scan(qp, q, proxy, x, m,
                                         proxy_norms, x_norms, tile=tile)
@@ -256,14 +265,15 @@ def golden_support_aggregate(x, idx, logits, backend: str | None = None,
     ``logits`` come from re-ranking distances (masking is the caller's
     job: NEG_INF entries get zero weight).  xla: scatter + GEMM
     (``strategy="dense"``, the default) or row gather + einsum
-    (``strategy="gather"``, sublinear in N); pallas*: gather + streaming
-    online-softmax kernel.
+    (``strategy="gather"``, sublinear in N); pallas*: row-fetch +
+    streaming online-softmax kernel.
     """
     backend = _resolve(backend)
+    check_rows(x)
     if backend == "xla":
         if (strategy or "dense") == "dense":
             return ref.scatter_aggregate_ref(x, idx, logits)
-        return ref.golden_support_aggregate_ref(x[idx], logits)
+        return ref.golden_support_aggregate_ref(gather_rows(x, idx), logits)
     return _sagg(x, idx, logits, interpret=(backend == "pallas_interpret"),
                  **kw)
 
@@ -287,14 +297,15 @@ def golden_partial_aggregate(x, idx, logits, strategy: str | None = None):
     whatever platform the mesh lives on (the same rationale as the
     standalone distributed path).
     """
+    check_rows(x)
     if idx is None:
         lg = logits.astype(jnp.float32)
         m = jnp.max(lg, axis=-1)
         p = jnp.exp(lg - m[:, None])
-        return dot_f32(p, x, ((1,), (0,))), m, jnp.sum(p, axis=-1)
+        return weigh_rows(p, x), m, jnp.sum(p, axis=-1)
     if (strategy or "gather") == "dense":
         return ref.scatter_partial_aggregate_ref(x, idx, logits)
-    return ref.partial_aggregate_ref(x[idx], logits)
+    return ref.partial_aggregate_ref(gather_rows(x, idx), logits)
 
 
 def ivf_screen_local(qp, offsets_loc, centroids, centroid_norms, w_lo, w_hi,
@@ -418,6 +429,7 @@ def golden_aggregate(q, x, sigma2: float, x_norms=None,
     baselines runnable at N where the dense matrix cannot be allocated.
     """
     backend = _resolve(backend)
+    check_rows(x)
     if backend == "xla":
         if stream:
             return full_scan_stream(q, x, float(sigma2), x_norms=x_norms,
@@ -441,6 +453,7 @@ def golden_full_partial(q, x, sigma2: float, x_norms=None,
     it runs inside ``shard_map``, where it compiles for whatever
     platform the mesh lives on.
     """
+    check_rows(x)
     if stream:
         return full_scan_partial_stream(q, x, float(sigma2),
                                         x_norms=x_norms,

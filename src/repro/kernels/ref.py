@@ -1,10 +1,15 @@
-"""Pure-jnp oracles for every Pallas kernel (tests assert allclose)."""
+"""Pure-jnp oracles for every Pallas kernel (tests assert allclose).
+
+A dataset operand ``x`` is ``[N, D]`` or the store rows ``[N, 1, D]``:
+the oracles contract its last axis (``common.dot_rows`` /
+``common.weigh_rows``) and never reshape it.
+"""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.common import HIGHEST, dot_f32
+from repro.kernels.common import HIGHEST, dot_rows, row_sq_norms, weigh_rows
 
 NEG_INF = -1e30
 
@@ -38,10 +43,9 @@ def pdist_ref(q: jnp.ndarray, x: jnp.ndarray,
     """Matmul-form pairwise squared distances; accepts precomputed row
     norms (e.g. +inf on padded/masked dataset rows -> +inf distance)."""
     q = q.astype(jnp.float32)
-    x = x.astype(jnp.float32)
     qn = jnp.sum(q * q, -1) if q_norms is None else q_norms.astype(jnp.float32)
-    xn = jnp.sum(x * x, -1) if x_norms is None else x_norms.astype(jnp.float32)
-    d2 = qn[:, None] + xn[None, :] - 2.0 * dot_f32(q, x, ((1,), (1,)))
+    xn = row_sq_norms(x) if x_norms is None else x_norms.astype(jnp.float32)
+    d2 = qn[:, None] + xn[None, :] - 2.0 * dot_rows(q, x)
     return jnp.maximum(d2, 0.0)
 
 
@@ -97,7 +101,7 @@ def golden_aggregate_ref(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
     inv = finite_inv_two_sigma2(sigma2)
     lg = jnp.maximum(-pdist_ref(q, x, x_norms=x_norms) * inv, NEG_INF)
     w = jax.nn.softmax(lg, axis=-1)
-    return dot_f32(w, x, ((1,), (0,))).astype(q.dtype)
+    return weigh_rows(w, x).astype(q.dtype)
 
 
 def scatter_aggregate_ref(x: jnp.ndarray, idx: jnp.ndarray,
@@ -114,7 +118,7 @@ def scatter_aggregate_ref(x: jnp.ndarray, idx: jnp.ndarray,
     w = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     ws = jnp.zeros((b, n), jnp.float32).at[
         jnp.arange(b)[:, None], idx].add(w)
-    return dot_f32(ws, x, ((1,), (0,)))
+    return weigh_rows(ws, x)
 
 
 def golden_support_aggregate_ref(xs: jnp.ndarray,
@@ -158,7 +162,7 @@ def scatter_partial_aggregate_ref(x: jnp.ndarray, idx: jnp.ndarray,
     l = jnp.sum(p, axis=-1)
     ws = jnp.zeros((b, n), jnp.float32).at[
         jnp.arange(b)[:, None], idx].add(p)
-    return dot_f32(ws, x, ((1,), (0,))), m, l
+    return weigh_rows(ws, x), m, l
 
 
 def flash_attention_ref(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,

@@ -49,7 +49,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
-from repro.kernels.common import dot_f32
+from repro.kernels.common import (check_rows, dot_f32, dot_rows,
+                                  row_sq_norms, weigh_rows)
 
 NEG_INF = -1e30
 DEFAULT_TILE = 4096
@@ -183,14 +184,16 @@ def full_scan_partial_stream(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
     — the [B, N] logits matrix of the dense form is never built, and
     (like :func:`screen_topm_scan`) a ragged final tile overlaps
     backwards with the re-seen columns masked to exactly zero weight
-    instead of padding the store.
+    instead of padding the store.  ``x`` is the store rows ``[N, 1, D]``;
+    each tile is contracted on its last axis, not reshaped.
     """
-    n, d = x.shape
+    check_rows(x)
+    n, d = x.shape[0], x.shape[-1]
     b = q.shape[0]
     q32 = q.astype(jnp.float32)
     qn = jnp.sum(q32 ** 2, -1)[:, None]
     if x_norms is None:
-        x_norms = jnp.sum(x.astype(jnp.float32) ** 2, -1)
+        x_norms = row_sq_norms(x)
     x_norms = x_norms.astype(jnp.float32)
     tile = min(tile, max(n, 1))
     # finite inverse temperature: degenerate sigma2 clamps every logit
@@ -203,7 +206,7 @@ def full_scan_partial_stream(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
         eff = jnp.minimum(start, n - tile)     # ragged tail: overlap back
         xt = jax.lax.dynamic_slice_in_dim(x, eff, tile).astype(jnp.float32)
         xnt = jax.lax.dynamic_slice_in_dim(x_norms, eff, tile)
-        dot = dot_f32(q32, xt, ((1,), (1,)))
+        dot = dot_rows(q32, xt)
         d2 = jnp.maximum(qn + xnt[None, :] - 2.0 * dot, 0.0)
         # +inf-norm (padded) rows clamp to the finite NEG_INF sentinel —
         # exp(NEG_INF - m) underflows to exactly 0 for any real logit,
@@ -216,7 +219,7 @@ def full_scan_partial_stream(q: jnp.ndarray, x: jnp.ndarray, sigma2: float,
         scale = jnp.exp(m_run - m_new)
         p = jnp.exp(lg - m_new[:, None])
         l_new = l_run * scale + jnp.sum(p, -1)
-        acc_new = acc * scale[:, None] + dot_f32(p, xt, ((1,), (0,)))
+        acc_new = acc * scale[:, None] + weigh_rows(p, xt)
         return (m_new, l_new, acc_new), None
 
     init = (jnp.full((b,), NEG_INF, jnp.float32),

@@ -86,7 +86,7 @@ def test_golden_rerank_parity(gmm_setup, backend):
     store, x = gmm_setup
     b = x.shape[0]
     cand = jnp.tile(jnp.arange(256)[None], (b, 1))
-    idx, d2 = ops.golden_rerank(x, store.X, cand, 32,
+    idx, d2 = ops.golden_rerank(x, store.rows, cand, 32,
                                 x_norms=store.x_norms, backend=backend)
     # eager oracle: broadcast-subtract distances, top-k
     d2_all = jnp.sum((x[:, None] - store.X[cand]) ** 2, -1)
@@ -117,7 +117,7 @@ def test_golden_support_aggregate_parity(gmm_setup, backend):
                                         (b, store.n)), -1)[:, :40]
     d2 = jnp.sum((x[:, None] - store.X[idx]) ** 2, -1)
     lg = -d2 / 0.7
-    out = ops.golden_support_aggregate(store.X, idx, lg, backend=backend)
+    out = ops.golden_support_aggregate(store.rows, idx, lg, backend=backend)
     w = jax.nn.softmax(lg, -1)
     eager = jnp.einsum("bk,bkd->bd", w, store.X[idx])
     np.testing.assert_allclose(np.asarray(out), np.asarray(eager),

@@ -26,6 +26,7 @@ from repro.kernels import ops, ref
 
 STORE = gmm(128, dim=8, seed=0)
 X = STORE.X
+ROWS = STORE.rows                 # the store rows [N, 1, D] the ops take
 XN = STORE.x_norms
 Q = jnp.asarray(np.random.default_rng(1).normal(size=(3, 8)), jnp.float32)
 
@@ -49,7 +50,7 @@ def test_finite_inv_two_sigma2():
 def test_full_scan_finite_at_extreme_sigma(backend, sigma2):
     """golden_aggregate degrades to a finite (data-mean-ish) estimate
     at degenerate sigma2 on every backend, streamed and dense."""
-    outs = [np.asarray(ops.golden_aggregate(Q, X, sigma2, x_norms=XN,
+    outs = [np.asarray(ops.golden_aggregate(Q, ROWS, sigma2, x_norms=XN,
                                             backend=backend, stream=s))
             for s in ((False, True) if backend == "xla" else (False,))]
     for out in outs:
@@ -67,7 +68,7 @@ def test_full_scan_partial_states_finite(sigma2):
     """The shard-local halves (dense + streamed) stay finite and agree
     under degenerate sigma2 (they used to ZeroDivisionError / NaN)."""
     for stream in (False, True):
-        acc, m, l = ops.golden_full_partial(Q, X, sigma2, x_norms=XN,
+        acc, m, l = ops.golden_full_partial(Q, ROWS, sigma2, x_norms=XN,
                                             stream=stream, tile=32)
         assert np.isfinite(np.asarray(acc)).all()
         assert np.isfinite(np.asarray(m)).all()     # NEG_INF sentinel, not -inf
@@ -80,7 +81,7 @@ def test_all_masked_support_aggregate_finite(backend):
     gathered rows, never 0/0."""
     idx = jnp.tile(jnp.arange(4)[None, :], (Q.shape[0], 1))
     lg = jnp.full((Q.shape[0], 4), ref.NEG_INF, jnp.float32)
-    out = np.asarray(ops.golden_support_aggregate(X, idx, lg,
+    out = np.asarray(ops.golden_support_aggregate(ROWS, idx, lg,
                                                   backend=backend))
     assert np.isfinite(out).all()
     np.testing.assert_allclose(out, np.tile(np.asarray(X[:4]).mean(0),
@@ -102,7 +103,7 @@ def test_surplus_screen_slots_stay_finite(backend):
                      ref.NEG_INF)
     lg = jnp.where(jnp.isnan(lg), ref.NEG_INF, lg)
     out = np.asarray(ops.golden_support_aggregate(
-        X, jnp.asarray(idx), lg,
+        ROWS, jnp.asarray(idx), lg,
         backend=backend, strategy="gather"))
     assert np.isfinite(out).all()
 
@@ -139,7 +140,7 @@ def test_normal_sigma_unchanged(backend):
     the precomputed 1/(2 sigma2) equals the old division bit-for-bit
     against the reference."""
     sigma2 = 0.37
-    out = np.asarray(ops.golden_aggregate(Q, X, sigma2, x_norms=XN,
+    out = np.asarray(ops.golden_aggregate(Q, ROWS, sigma2, x_norms=XN,
                                           backend=backend))
     d2 = np.asarray(ref.pdist_ref(Q, X, x_norms=XN), np.float64)
     w = np.exp(-(d2 - d2.min(1, keepdims=True)) / (2 * sigma2))

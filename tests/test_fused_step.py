@@ -109,8 +109,9 @@ def test_fused_surplus_slots_weightless(backend):
     kx, kq = jax.random.split(jax.random.PRNGKey(3))
     x = jax.random.normal(kx, (n, d), jnp.float32)
     q = jax.random.normal(kq, (4, d), jnp.float32)
-    out_big = ops.fused_step(q, q, x, x, 80, 10, 0.5, backend=backend)
-    out_fit = ops.fused_step(q, q, x, x, n, 10, 0.5, backend=backend)
+    rows = x[:, None, :]                  # the store rows the ops take
+    out_big = ops.fused_step(q, q, rows, x, 80, 10, 0.5, backend=backend)
+    out_fit = ops.fused_step(q, q, rows, x, n, 10, 0.5, backend=backend)
     assert np.isfinite(np.asarray(out_big)).all()
     np.testing.assert_allclose(np.asarray(out_big), np.asarray(out_fit),
                                rtol=1e-6, atol=1e-6)
@@ -125,7 +126,7 @@ def test_fused_all_masked_is_finite(backend):
     kx, kq = jax.random.split(jax.random.PRNGKey(4))
     x = jax.random.normal(kx, (n, d), jnp.float32)
     q = jax.random.normal(kq, (3, d), jnp.float32)
-    out = ops.fused_step(q, q, x, x, 16, 4, jnp.asarray(0.5),
+    out = ops.fused_step(q, q, x[:, None, :], x, 16, 4, jnp.asarray(0.5),
                          backend=backend, m_t=jnp.asarray(0),
                          k_t=jnp.asarray(0))
     assert np.isfinite(np.asarray(out)).all()

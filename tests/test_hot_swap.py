@@ -172,7 +172,7 @@ def test_probe_quarantines_poisoned_epoch(serve_env):
     before = rt.engine.serving_epoch
     y_pre = _serve_one(rt, 20, seed=8)
     ds, ix = lc.view()
-    poisoned = ds._replace(X=jnp.full_like(ds.X, jnp.nan))
+    poisoned = ds._replace(rows=jnp.full_like(ds.rows, jnp.nan))
     with pytest.raises(EpochProbeError):
         rt.hot_swap(poisoned, ix)
     assert rt.engine.serving_epoch == before        # flip never happened

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import (GoldDiff, OptimalDenoiser,
                         make_schedule, sample, sample_scan,
@@ -114,3 +115,23 @@ def test_conditional_store_restriction():
     sub = restrict(st, idx)
     assert sub.n == int(idx.shape[0])
     assert bool((sub.labels == 0).all())
+
+
+@pytest.mark.parametrize("extra", [-4059, 0, 37])
+def test_make_store_rows_and_chunked_proxy(extra):
+    """The store keeps its rows once as [N, 1, D]; ``X`` reads them on
+    the host as [N, D], and the proxy pooled a chunk of rows at a time
+    (the last chunk overlapping back) equals the proxy of the whole
+    image array, bit for bit."""
+    from repro.core.dataset import PROXY_CHUNK, make_store
+    n = PROXY_CHUNK + extra
+    x = jax.random.normal(jax.random.PRNGKey(3), (n, 8 * 8 * 3))
+    store = make_store(x, (8, 8, 3))
+    assert store.rows.shape == (n, 1, 8 * 8 * 3)
+    assert (store.n, store.dim) == (n, 8 * 8 * 3)
+    assert isinstance(store.X, np.ndarray)
+    np.testing.assert_array_equal(store.X, np.asarray(x))
+    whole = downsample_proxy(x.reshape(n, 8, 8, 3), 4)
+    np.testing.assert_array_equal(np.asarray(store.proxy), np.asarray(whole))
+    np.testing.assert_allclose(np.asarray(store.x_norms),
+                               np.sum(np.asarray(x) ** 2, -1), rtol=1e-6)

@@ -153,11 +153,12 @@ def test_full_scan_stream_matches_dense():
     for sig2 in (0.05, 0.7, 4.0):
         dense = np.asarray(ref.golden_aggregate_ref(q, x, sig2))
         stream = np.asarray(ops.golden_aggregate(
-            q, x, sig2, backend="xla", stream=True, tile=128))
+            q, x[:, None, :], sig2, backend="xla", stream=True, tile=128))
         np.testing.assert_allclose(stream, dense, rtol=1e-5, atol=1e-5)
-        acc_s, m_s, l_s = ops.golden_full_partial(q, x, sig2, stream=True,
-                                                  tile=100)   # ragged tail
-        acc_d, m_d, l_d = ops.golden_full_partial(q, x, sig2, stream=False)
+        acc_s, m_s, l_s = ops.golden_full_partial(
+            q, x[:, None, :], sig2, stream=True, tile=100)   # ragged tail
+        acc_d, m_d, l_d = ops.golden_full_partial(q, x[:, None, :], sig2,
+                                                  stream=False)
         np.testing.assert_allclose(np.asarray(m_s), np.asarray(m_d),
                                    rtol=1e-6, atol=1e-6)
         np.testing.assert_allclose(np.asarray(acc_s / l_s[:, None]),

@@ -132,7 +132,7 @@ def local(neg_sh, X_sh, idx_sh):
     neg_l, X_l, idx_l = neg_sh[0], X_sh[0], idx_sh[0]
     kth = crossshard_kth(neg_l, k, k, "data")
     lg = jnp.where(neg_l >= kth[:, None], neg_l / (2.0 * s2), -1e30)
-    acc, m, l = ops.golden_partial_aggregate(X_l, idx_l, lg)
+    acc, m, l = ops.golden_partial_aggregate(X_l[:, None, :], idx_l, lg)
     return lse_merge_mean(acc, m, l, "data")
 
 sp = P("data")
@@ -215,7 +215,7 @@ def test_sharded_layout_single_device():
     lay = shard_layout(store, mesh, "data")
     assert lay.n_loc == 257 and not lay.indexed
     np.testing.assert_array_equal(np.asarray(lay.ids)[0], np.arange(257))
-    np.testing.assert_allclose(np.asarray(lay.X)[0], np.asarray(store.X))
+    np.testing.assert_allclose(np.asarray(lay.X)[0], np.asarray(store.rows))
 
     ix = build_index(store, num_clusters=8)
     lay = shard_layout(store, mesh, "data", index=ix)
@@ -223,6 +223,6 @@ def test_sharded_layout_single_device():
     perm = np.asarray(ix.perm)
     np.testing.assert_array_equal(np.asarray(lay.ids)[0], perm)
     np.testing.assert_allclose(np.asarray(lay.X)[0],
-                               np.asarray(store.X)[perm])
+                               np.asarray(store.rows)[perm])
     np.testing.assert_array_equal(np.asarray(lay.offsets)[0],
                                   np.asarray(ix.offsets))

@@ -13,7 +13,9 @@ to a Pallas custom call; the streamed screen and the fused candidate
 pass compile to plain XLA (``lax.scan``), because Mosaic has no
 ``top_k`` for their merge.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,8 +68,9 @@ def _shapes(width):
     return n, d, dp, n // 4, n // 10
 
 
-def _stage(name, n, d, dp, m, k):
-    """(fn, [(shape, dtype)], expects_kernel) for one engine stage."""
+def _stage(name, n, d, dp, m, k, xdt=jnp.float32):
+    """(fn, [(shape, dtype)], expects_kernel) for one engine stage, the
+    store rows in ``xdt``."""
     f32, i32 = jnp.float32, jnp.int32
     if name == "screen_materialized":
         return (lambda qp, p, pn: ops.screen_topm(
@@ -80,18 +83,18 @@ def _stage(name, n, d, dp, m, k):
     if name == "rerank":
         return (lambda q, x, c, xn: ops.golden_rerank(
             q, x, c, k, x_norms=xn, backend="pallas"),
-            [((B, d), f32), ((n, d), f32), ((B, m), i32), ((n,), f32)],
+            [((B, d), f32), ((n, 1, d), xdt), ((B, m), i32), ((n,), f32)],
             True)
     if name == "aggregate":
         return (lambda x, i, lg: ops.golden_support_aggregate(
             x, i, lg, backend="pallas"),
-            [((n, d), f32), ((B, k), i32), ((B, k), f32)], True)
+            [((n, 1, d), xdt), ((B, k), i32), ((B, k), f32)], True)
     if name == "fused_step":
         return (lambda q, qp, x, p, xn, pn: ops.fused_step(
             q, qp, x, p, m, k, 0.5, x_norms=xn, proxy_norms=pn,
             backend="pallas", stream=True),
-            [((B, d), f32), ((B, dp), f32), ((n, d), f32), ((n, dp), f32),
-             ((n,), f32), ((n,), f32)], True)
+            [((B, d), f32), ((B, dp), f32), ((n, 1, d), f32),
+             ((n, dp), f32), ((n,), f32), ((n,), f32)], True)
     if name == "centroid_scan":
         c = int(n ** 0.5)
         return (lambda qp, cs, cn: ops.centroid_scan(
@@ -100,7 +103,7 @@ def _stage(name, n, d, dp, m, k):
     if name == "full_scan":
         return (lambda q, x, xn: ops.golden_aggregate(
             q, x, 0.5, x_norms=xn, backend="pallas"),
-            [((B, d), f32), ((n, d), f32), ((n,), f32)], True)
+            [((B, d), f32), ((n, 1, d), xdt), ((n,), f32)], True)
     raise KeyError(name)
 
 
@@ -121,13 +124,75 @@ def test_stage_compiles_for_v5e(one_chip, width, stage):
             + mem.temp_size_in_bytes) < HBM_BYTES
 
 
+@pytest.mark.parametrize("stage", ["rerank", "aggregate", "fused_step",
+                                   "full_scan"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_row_fetch_stage_reads_the_store_in_place(one_chip, width, stage):
+    """The re-rank and support-aggregate stages take the store in the
+    kernels' ``[N, 1, D]`` layout and fetch each candidate row inside
+    the kernel: the compiled program holds no gathered ``[B, m, D]`` /
+    ``[B, k, D]`` f32 copy of the candidates, and its temporaries stay
+    below the store's own bytes (no per-call relayout of the store).
+    The fused step (tiles of the store contracted in XLA, the fetch in
+    its epilogue) and the full scan hold to the same."""
+    n, d, dp, m, k = _shapes(width)
+    fn, specs, _ = _stage(stage, n, d, dp, m, k)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert KERNEL in text
+    # any f32 array of half the candidates' rows or more, the store aside
+    cands = B * (m if stage == "rerank" else k) * d
+    copies = {dims for dims in re.findall(r"f32\[([\d,]+)\]", text)
+              if dims != f"{n},1,{d}"
+              and math.prod(int(v) for v in dims.split(",")) >= cands // 2}
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < n * d * 4
+
+
+def _f32_arrays(text, at_least):
+    """Shapes of the f32 arrays in compiled HLO ``text`` with at least
+    ``at_least`` elements."""
+    return {dims for dims in re.findall(r"f32\[([\d,]+)\]", text)
+            if math.prod(int(v) for v in dims.split(",")) >= at_least}
+
+
+@pytest.mark.parametrize("stage", ["rerank", "aggregate", "full_scan"])
+@pytest.mark.parametrize("width", list(WIDTHS))
+def test_16bit_store_stage_reads_the_store_in_place(one_chip, width, stage):
+    """A bf16 store's ``[N, 1, D]`` rows are laid out in ``[N, D]``
+    tiles, which no one-row DMA can address: the re-rank and aggregate
+    stages gather their candidates in bf16 (the one copy the XLA gather
+    makes) and the full scan reads the store in blocks.  No stage widens
+    the rows to an f32 copy or relays the store out per call."""
+    n, d, dp, m, k = _shapes(width)
+    fn, specs, _ = _stage(stage, n, d, dp, m, k, xdt=jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in specs]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert KERNEL in text
+    # the gathered bf16 candidates, padded to whole tiles
+    gathered = {"rerank": B * (m + 255) // 256 * 256 * d * 2,
+                "aggregate": B * (k + 255) // 256 * 256 * d * 2,
+                "full_scan": 0}[stage]
+    assert not _f32_arrays(text, min(n, B * k) * d // 2)
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < gathered + n * d * 2 // 2)
+
+
 @pytest.mark.parametrize("path", ["fused", "staged", "indexed"])
 def test_sharded_step_compiles_for_v5e_2x2(topo, path):
     """The sharded engine's shard-local steps across the four chips of
     the mesh, at CIFAR-10 width: the fused exact step, the staged exact
     step (``select`` and the static steps it shares its stages with) and
     the indexed step (globally probed windows, ``ivf_screen_local``),
-    each ending in the cross-shard top-k threshold and LSE merge."""
+    each ending in the cross-shard top-k threshold and LSE merge.  No
+    step relays out its shard of the store: no f32 array of the local
+    store's size other than the rows themselves, and temporaries below
+    half the local store's bytes over the ``[B, k_cap, D]`` golden rows
+    that the shard-local partial aggregate gathers."""
     n, d, dp, m, k = _shapes("cifar10")
     mesh = Mesh(topo.devices[:4], ("data",))
     n_loc = n // 4
@@ -159,7 +224,7 @@ def test_sharded_step_compiles_for_v5e_2x2(topo, path):
     step = shard_map_compat(local, mesh, (spec,) * 6 + (PartitionSpec(),) * 4,
                             PartitionSpec())
     f32, i32 = jnp.float32, jnp.int32
-    args = [jax.ShapeDtypeStruct((n, d), f32, sharding=rows),
+    args = [jax.ShapeDtypeStruct((n, 1, d), f32, sharding=rows),
             jax.ShapeDtypeStruct((n,), f32, sharding=rows),
             jax.ShapeDtypeStruct((n, dp), f32, sharding=rows),
             jax.ShapeDtypeStruct((n,), f32, sharding=rows),
@@ -174,3 +239,9 @@ def test_sharded_step_compiles_for_v5e_2x2(topo, path):
     assert KERNEL in text and "all-gather" in text
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < HBM_BYTES
+    store_sized = {dims for dims in _f32_arrays(text, n_loc * d)
+                   if math.prod(int(v) for v in dims.split(","))
+                   in (n_loc * d, n * d)}
+    assert store_sized <= {f"{n_loc},1,{d}", f"{n},1,{d}"}, store_sized
+    assert mem.temp_size_in_bytes < (B * k_cap + n_loc // 2) * d * 4, \
+        mem.temp_size_in_bytes
