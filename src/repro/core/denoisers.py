@@ -20,7 +20,8 @@ training points — this is the hook GoldDiff plugs into (Tab. 5
 """
 from __future__ import annotations
 
-from typing import Literal
+import functools
+from typing import Literal, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +31,7 @@ from repro.core import streaming
 from repro.core.dataset import DatasetStore, pairwise_sq_dists
 from repro.core.schedules import Schedule
 from repro.kernels import ops
-from repro.kernels.common import gather_rows
+from repro.kernels.common import HIGHEST, check_rows, gather_rows
 
 Array = jnp.ndarray
 Weighting = Literal["ss", "wss"]
@@ -108,12 +109,110 @@ class OptimalDenoiser:
 # Wiener (linear MMSE; N enters only through precomputed statistics)
 # ---------------------------------------------------------------------------
 
+class WienerBasis(NamedTuple):
+    """The Gaussian fit of a store: ``Sigma = V diag(lam) V^T``."""
+
+    mu: Array                   # [D] mean of the store's rows
+    V: Array                    # [D, r] top-r eigenvectors of Sigma
+    lam: Array                  # [r] their eigenvalues, descending, >= 0
+
+
+# Rows of the store centred and contracted at a time: 64 MiB of fp32
+# (5,461 rows at D=3072, 1,365 at D=12288), so no step of the Gram sum
+# holds more than a block beside the [D, D] accumulator.
+WIENER_BLOCK_BYTES = 1 << 26
+
+
+def wiener_basis(rows: Array, x_norms: Array, rank: int | None = None
+                 ) -> WienerBasis:
+    """Mean and top-``rank`` eigenpairs of the covariance of the store
+    rows ``[N, 1, D]``, on the device: one program
+    (:func:`_wiener_basis`).
+
+    Rows whose ``x_norms`` entry is ``+inf`` are padding (the repo-wide
+    convention: capacity-padded and sharded stores) and are left out.
+    The rank defaults to ``min(N, D)``, the SVD form's ``lam = s^2 / N``
+    and ``V = Vt^T``.  Rows sharded over their leading axis are split
+    into the shards' row ranges, so each device sums the Gram matrix of
+    its own rows and one all-reduce adds them."""
+    n, _, d = check_rows(rows).shape
+    r = min(n, d) if rank is None else min(int(rank), n, d)
+    groups = _row_shards(rows)
+    block = max(1, min(n // groups, WIENER_BLOCK_BYTES // (4 * d)))
+    return _wiener_basis(rows, x_norms, rank=r, groups=groups, block=block)
+
+
+def _row_shards(rows: Array) -> int:
+    """How many ways ``rows`` is split along its leading axis."""
+    sharding = getattr(rows, "sharding", None)
+    if sharding is None:
+        return 1
+    return rows.shape[0] // sharding.shard_shape(rows.shape)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "groups", "block"))
+def _wiener_basis(rows, x_norms, rank: int, groups: int, block: int
+                  ) -> WienerBasis:
+    """``rows [N, 1, D]`` -> :class:`WienerBasis`, all in fp32.
+
+    The leading axis is split into ``groups`` row ranges (a bitcast);
+    ``C = sum (x - mu)(x - mu)^T / n`` is summed ``block`` rows of each
+    range at a time, each block centred as it is read, at ``HIGHEST``
+    precision; the eigendecomposition of ``C`` gives the basis.  The last block
+    of a range starts early rather than padding the store, and masks
+    the rows it re-reads."""
+    n, _, d = rows.shape
+    per = n // groups
+    xg = rows.reshape(groups, per, 1, d)
+    valid = jnp.isfinite(x_norms).reshape(groups, per)
+    count = jnp.sum(valid).astype(jnp.float32)
+    mu = jnp.sum(jnp.where(valid[..., None, None], xg, 0.0),
+                 axis=(0, 1, 2), dtype=jnp.float32) / count
+
+    def add_block(i, gram):
+        start = jnp.minimum(i * block, per - block)
+        xb = jax.lax.dynamic_slice_in_dim(xg, start, block, axis=1)
+        keep = jax.lax.dynamic_slice_in_dim(valid, start, block, axis=1)
+        keep &= start + jnp.arange(block) >= i * block
+        xc = jnp.where(keep[..., None, None], xb.astype(jnp.float32) - mu,
+                       0.0)
+        return gram + jnp.einsum("gnod,gnoe->gde", xc, xc,
+                                 precision=HIGHEST)
+
+    gram = jax.lax.fori_loop(0, -(-per // block), add_block,
+                             jnp.zeros((groups, d, d), jnp.float32))
+    lam, vecs = _eigh(jnp.sum(gram, 0) / count)
+    return WienerBasis(mu=mu, V=vecs[:, ::-1][:, :rank],
+                       lam=jnp.maximum(lam[::-1][:rank], 0.0))
+
+
+def _eigh(c: Array) -> tuple[Array, Array]:
+    """(eigenvalues ascending, eigenvectors) of the symmetric ``c``.  On
+    a TPU the Jacobi custom call (one op; at D=12288 under five
+    ``[D, D]`` temporaries): JAX's default there, QDWH, took 79 s to
+    compile for a v5e at D=1024 alone and held about nine."""
+    def jacobi(c):
+        vecs, lam = jax.lax.linalg.eigh(
+            c, implementation=jax.lax.linalg.EighImplementation.JACOBI)
+        return lam, vecs
+    return jax.lax.platform_dependent(
+        c, tpu=jacobi, default=lambda c: tuple(jnp.linalg.eigh(c)))
+
+
+def wiener_x0(basis: WienerBasis, x_t: Array, a, b) -> Array:
+    """The Wiener posterior mean of ``x_t`` at the noise level
+    ``(a, b)``: ``mu + V diag(a lam / (a^2 lam + b^2)) V^T (x_t - a mu)``."""
+    mu, V, lam = basis
+    coeff = (a * lam) / (a * a * lam + b * b)                 # [r]
+    return mu + (((x_t - a * mu) @ V) * coeff) @ V.T
+
+
 class WienerDenoiser:
     """x0_hat = mu + Sigma a (a^2 Sigma + b^2 I)^-1 (x_t - a mu).
 
-    Sigma is represented through the SVD of the centered data matrix, so the
-    inverse is exact and rank-limited (never materializes the D x D matrix
-    unless rank == D).
+    Sigma is represented through its top-``rank`` eigenpairs
+    (:func:`wiener_basis`, built on the device from the store's rows),
+    so the inverse is exact and rank-limited.
     """
 
     name = "wiener"
@@ -122,24 +221,13 @@ class WienerDenoiser:
                  rank: int | None = None):
         self.store = store
         self.schedule = schedule
-        x = np.asarray(store.X, np.float64)
-        self.mu = jnp.asarray(x.mean(0), jnp.float32)
-        xc = x - x.mean(0)
-        r = min(x.shape) if rank is None else min(rank, min(x.shape))
-        # economical SVD on the smaller Gram side
-        _, s, vt = np.linalg.svd(xc, full_matrices=False)
-        self.V = jnp.asarray(vt[:r].T, jnp.float32)          # [D, r]
-        self.lam = jnp.asarray((s[:r] ** 2) / x.shape[0], jnp.float32)
+        self.basis = wiener_basis(store.rows, store.x_norms, rank)
 
     def __call__(self, x_t: Array, t: int, support: Array | None = None) -> Array:
         # support is meaningless for a statistics-only estimator (paper
         # excludes Wiener from the orthogonality study for this reason).
-        a = float(self.schedule.a[t])
-        b = float(self.schedule.b[t])
-        z = x_t - a * self.mu
-        coeff = (a * self.lam) / (a * a * self.lam + b * b)   # [r]
-        proj = z @ self.V                                     # [B, r]
-        return self.mu + (proj * coeff) @ self.V.T
+        return wiener_x0(self.basis, x_t, float(self.schedule.a[t]),
+                         float(self.schedule.b[t]))
 
 
 # ---------------------------------------------------------------------------

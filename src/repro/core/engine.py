@@ -629,12 +629,7 @@ class GoldDiffEngine:
             return jf.lower(ops_, *aot_specs).compile()
 
         ops0 = self.current_operands()
-        if self._compile_pool is None:
-            first = compile_for(ops0)
-        else:
-            first = self._compile_pool.submit(compile_for, ops0)
-            self._compile_futures.append(first)
-        execs = {self._ops_sig(ops0): first}
+        execs = {self._ops_sig(ops0): self._submit(compile_for, ops0)}
 
         def call(*args):
             ops_ = self.current_operands()
@@ -649,6 +644,29 @@ class GoldDiffEngine:
             return compiled(ops_, *args)
 
         return call
+
+    def aot(self, fn, specs: tuple):
+        """``jax.jit(fn)`` compiled ahead of time for ``specs`` (a tuple
+        of ``ShapeDtypeStruct``), every operand an argument: inside
+        :meth:`parallel_compiles` on its pool, and the first call waits
+        for it."""
+        box = [self._submit(lambda: jax.jit(fn).lower(*specs).compile())]
+
+        def call(*args):
+            if isinstance(box[0], Future):
+                box[0] = box[0].result()
+            return box[0](*args)
+
+        return call
+
+    def _submit(self, compile_fn, *args):
+        """``compile_fn(*args)`` now, or as a Future on the compile pool
+        inside :meth:`parallel_compiles`."""
+        if self._compile_pool is None:
+            return compile_fn(*args)
+        fut = self._compile_pool.submit(compile_fn, *args)
+        self._compile_futures.append(fut)
+        return fut
 
     @contextlib.contextmanager
     def parallel_compiles(self):

@@ -111,7 +111,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import build_plan
-from repro.core.denoisers import WienerDenoiser
+from repro.core.denoisers import WienerBasis, wiener_basis, wiener_x0
 from repro.core.sampler import (plan_segment, plan_segment_key,
                                 plan_segment_mixed, plan_segment_mixed_key,
                                 sample_plan)
@@ -380,7 +380,11 @@ class ServeRuntime:
         self._retry_seq = 0
         self._warm = False
         self._builds_warm = 0
-        self._wiener: WienerDenoiser | None = None
+        # the Gaussian rung: one basis per store epoch, and one grid
+        # length that holds every plan variant's and the scan's grid
+        self._bases: dict[int, WienerBasis] = {}
+        self._gauss_nts = max([eng.num_steps + 1]
+                              + [len(p.ts) for p in self.plans.values()])
         self._thread: threading.Thread | None = None
         self._stop = threading.Event()
         self.counters = {k: 0 for k in (
@@ -404,48 +408,74 @@ class ServeRuntime:
             reservoir=self.cfg.latency_reservoir)
         self.registry.register(self._lat_hist)
 
-    # -- Gaussian (Wiener) fallback programs ---------------------------------
-    def _wiener_den(self) -> WienerDenoiser:
-        if self._wiener is None:
-            self._wiener = WienerDenoiser(self.eng.store, self.eng.schedule)
-        return self._wiener
+    # -- Gaussian (Wiener) fallback ------------------------------------------
+    def _build_basis(self, epoch: int) -> WienerBasis:
+        """The Wiener basis of ``epoch``'s store rows, on the device (a
+        ``runtime.wiener_basis`` span).  Every epoch has the same shapes,
+        so after the first this program never compiles."""
+        with self.engine.at_epoch(epoch):
+            ops_ = self.engine.current_operands()
+        dim = self.eng.store.dim
+        with obs_trace.tracer().span("runtime.wiener_basis", dim=dim,
+                                     rank=min(self.eng.store.n, dim),
+                                     epoch=epoch):
+            return jax.block_until_ready(wiener_basis(ops_.X, ops_.x_norms))
 
-    def _gauss_program(self, bucket: int, nts: int):
+    def _basis(self, epoch: int) -> WienerBasis:
+        """``epoch``'s basis: built by ``warmup`` for the serving epoch
+        and by ``hot_swap`` before its flip, else here on first use."""
+        basis = self._bases.get(epoch)
+        if basis is None:
+            basis = self._bases[epoch] = self._build_basis(epoch)
+        return basis
+
+    def _gauss_program(self, bucket: int, compile_only: bool = False):
         """Compiled closed-form-Gaussian DDIM segment for one batch
-        bucket: ``fn(x, ts, start, stop)`` runs steps [start, stop) of a
-        length-``nts`` timestep grid with the Wiener posterior mean as
-        the denoiser.  Rank-limited SVD form — finite for every finite
-        input, no data gathers, no screening: the ladder's last rung.
+        bucket: ``fn(x, ts, start, stop, mu, V, lam)`` runs steps
+        [start, stop) of the timestep grid ``ts`` (padded to
+        ``_gauss_nts`` entries) with the Wiener posterior mean of the
+        basis ``(mu, V, lam)`` as the denoiser.  Rank-limited
+        eigenbasis form — finite for every finite input, no data
+        gathers, no screening: the ladder's last rung.  The basis is an
+        argument, so the key holds shapes only and one program serves
+        every epoch and every store of these shapes.
 
         The ``"gauss_seg"`` kind is deliberately NOT in the fault
         injector's default targets; a fallback that can itself be
         faulted is not a fallback.
         """
-        den = self._wiener_den()
         sched = self.eng.schedule
         clip = self.eng.clip_value
         dim = self.eng.store.dim
-        key = ("gauss_seg", bucket, dim, nts,
+        rank = min(self.eng.store.n, dim)
+        nts = self._gauss_nts
+        key = ("gauss_seg", bucket, dim, rank, nts,
                None if clip is None else float(clip))
 
         def build():
-            mu, V, lam = den.mu, den.V, den.lam
             a = jnp.asarray(sched.a)
             b = jnp.asarray(sched.b)
 
-            def seg(x, ts, start, stop):
+            def seg(x, ts, start, stop, mu, V, lam):
+                basis = WienerBasis(mu, V, lam)
+
                 def body(i, x):
                     t, tp = ts[i], ts[i + 1]
-                    at, bt = a[t], b[t]
-                    coeff = (at * lam) / (at * at * lam + bt * bt)
-                    x0 = mu + (((x - at * mu) @ V) * coeff) @ V.T
+                    x0 = wiener_x0(basis, x, a[t], b[t])
                     if clip is not None:
                         x0 = jnp.clip(x0, -clip, clip)
-                    eps = (x - at * x0) / bt
+                    eps = (x - a[t] * x0) / b[t]
                     return a[tp] * x0 + b[tp] * eps
                 return jax.lax.fori_loop(start, stop, body, x)
 
-            return jax.jit(seg)
+            if not compile_only:
+                return jax.jit(seg)
+            f32, i32 = jnp.float32, jnp.int32
+            return self.engine.aot(seg, tuple(
+                jax.ShapeDtypeStruct(s, dt) for s, dt in (
+                    ((bucket, dim), f32), ((nts,), i32), ((), i32),
+                    ((), i32), ((dim,), f32), ((dim, rank), f32),
+                    ((rank,), f32))))
 
         return self.engine.program(key, build)
 
@@ -481,9 +511,10 @@ class ServeRuntime:
 
     def _run_gauss(self, wave: _Wave, seg: int, x: np.ndarray) -> np.ndarray:
         ts, start, stop = self._segment_grid(wave, seg)
-        fn = self._gauss_program(wave.bucket, len(ts))
-        out = fn(jnp.asarray(x), jnp.asarray(ts, jnp.int32),
-                 np.int32(start), np.int32(stop))
+        ts = ts + ts[-1:] * (self._gauss_nts - len(ts))
+        fn = self._gauss_program(wave.bucket)
+        out = fn(x, np.asarray(ts, np.int32), np.int32(start),
+                 np.int32(stop), *self._basis(wave.epoch))
         self.counters["gauss_segments"] += 1
         return np.asarray(jax.block_until_ready(out), np.float32)
 
@@ -504,9 +535,6 @@ class ServeRuntime:
             dim = self.eng.store.dim
             call_masked = self.eng.denoiser.call_masked \
                 if self.eng._scan_compatible() else None
-            nts_set = {self.eng.num_steps + 1}
-            for p in self.plans.values():
-                nts_set.add(len(p.ts))
             for b in self.eng.batch_buckets():
                 shape = (b, dim)
                 if call_masked is not None:
@@ -545,14 +573,16 @@ class ServeRuntime:
                             jax.block_until_ready(fn(
                                 jnp.zeros(shape, jnp.float32),
                                 jnp.full((b,), pb.start, jnp.int32)))
-                for nts in sorted(nts_set):
-                    ts = np.arange(nts, dtype=np.int32)[::-1].copy()
-                    ts = ts * 0 + 1          # any valid grid; compile only
-                    fn = self._gauss_program(b, nts)
-                    jax.block_until_ready(
-                        fn(jnp.zeros(shape, jnp.float32),
-                           jnp.asarray(ts, jnp.int32), np.int32(0),
-                           np.int32(1)))
+                fn = self._gauss_program(b, compile_only=aot)
+                if not aot:
+                    jax.block_until_ready(fn(
+                        jnp.zeros(shape, jnp.float32),
+                        jnp.ones((self._gauss_nts,), jnp.int32),
+                        jnp.int32(0), jnp.int32(1),
+                        *self._basis(self.engine.serving_epoch)))
+            # the serving epoch's basis, on this thread while the pool
+            # compiles
+            self._basis(self.engine.serving_epoch)
             if self.monitor is not None:
                 # recall probes fire at executed-step timesteps of any
                 # plan variant (and the scan grid): warm every one of
@@ -580,7 +610,7 @@ class ServeRuntime:
         aggregate) before any user row ever touches them."""
         b = self.eng.batch_buckets()[0]
         shape = (b, self.eng.store.dim)
-        x = jnp.zeros(shape, jnp.float32)
+        x = np.zeros(shape, np.float32)     # host zeros: no eager compile
         with self.engine.at_epoch(epoch):
             if self.eng.mode == "plan":
                 pb = self.eng.plan.buckets[0]
@@ -605,13 +635,13 @@ class ServeRuntime:
         Installs ``(store, index)`` as a standby epoch in the warmed
         engine (same-shape contract enforced by ``engine.swap_compat``
         — the appendable lifecycle's capacity-padded views satisfy it
-        by construction), probes it (:meth:`_probe_epoch`), then flips
-        the serving epoch under the scheduler lock.  Waves admitted
-        before the flip finish on their own epoch (``_Wave.epoch``);
-        waves admitted after see the new store.  A failed probe
-        quarantines the epoch — it is retired, ``epoch_quarantined``
-        increments, :class:`EpochProbeError` propagates, and the old
-        epoch keeps serving untouched.
+        by construction), probes it (:meth:`_probe_epoch`), builds its
+        Gaussian-rung basis, then flips the serving epoch under the
+        scheduler lock.  Waves admitted before the flip finish on their
+        own epoch (``_Wave.epoch``); waves admitted after see the new
+        store.  A failed probe quarantines the epoch — it is retired,
+        ``epoch_quarantined`` increments, :class:`EpochProbeError`
+        propagates, and the old epoch keeps serving untouched.
 
         Returns the installed epoch id (``epoch`` if given — e.g. the
         lifecycle's on-disk epoch number — else the next free int).
@@ -638,7 +668,11 @@ class ServeRuntime:
                     raise
                 raise EpochProbeError(
                     f"epoch {epoch} probe failed: {e}") from e
+        # the Gaussian rung follows the epoch: its basis is built before
+        # the flip (same shapes as the serving one's, so no compile)
+        basis = self._build_basis(epoch)
         with self._lock:
+            self._bases[epoch] = basis
             prev = self.engine.serving_epoch
             self.engine.set_serving_epoch(epoch)
             self.counters["hot_swaps"] += 1
@@ -655,6 +689,8 @@ class ServeRuntime:
         live.add(self.engine.serving_epoch)
         for e in [e for e in self.engine._epochs if e not in live]:
             self.engine.retire_epoch(e)
+        self._bases = {e: b for e, b in self._bases.items()
+                       if e in self.engine._epochs}
 
     # -- admission ------------------------------------------------------------
     def submit(self, req: Request) -> Ticket:

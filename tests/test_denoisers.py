@@ -54,6 +54,73 @@ def test_wiener_is_linear_mmse_on_gaussian():
     assert mse_w < mse_mean and mse_w < mse_id
 
 
+def _spread_data(n, d, seed):
+    """[n, d] rows off the origin with a spread spectrum (scales 3 to
+    0.3 along a random rotation), so each eigenvector is well defined."""
+    rng = np.random.default_rng(seed)
+    q, _ = np.linalg.qr(rng.normal(size=(d, d)))
+    scales = np.geomspace(3.0, 0.3, d)
+    return (rng.normal(size=(n, d)) * scales) @ q.T + 0.7
+
+
+def _rel(a, b):
+    return float(np.linalg.norm(np.asarray(a, np.float64) - b)
+                 / np.linalg.norm(b))
+
+
+@pytest.mark.parametrize("n,d", [(24, 40), (300, 16)])
+def test_wiener_basis_matches_float64_svd(n, d):
+    """The device basis (fp32 Gram matrix and ``eigh``) against the SVD
+    of the centred rows in float64, for N < D and N > D: the
+    eigenvalues, the projectors onto the leading eigenvectors (their
+    signs are free) and the Wiener x0 at three timesteps.  Tolerance:
+    1e-4 relative, since an fp32 eigendecomposition is compared with a
+    float64 one."""
+    from repro.core.dataset import make_store
+    from repro.core.denoisers import wiener_basis, wiener_x0
+    x = _spread_data(n, d, seed=n)
+    store = make_store(x.astype(np.float32), (d,), proxy_factor=1)
+    mu, V, lam = wiener_basis(store.rows, store.x_norms)
+    r = min(n, d)
+    assert V.shape == (d, r) and lam.shape == (r,)
+
+    x64 = np.asarray(store.X, np.float64)
+    mu_ref = x64.mean(0)
+    _, s, vt = np.linalg.svd(x64 - mu_ref, full_matrices=False)
+    lam_ref, v_ref = s ** 2 / n, vt.T
+    assert _rel(mu, mu_ref) < 1e-4
+    assert _rel(lam, lam_ref) < 1e-4
+    V = np.asarray(V, np.float64)
+    for k in {r // 2, min(n - 1, d)}:    # rank n - 1 once centred
+        assert _rel(V[:, :k] @ V[:, :k].T, v_ref[:, :k] @ v_ref[:, :k].T) \
+            < 1e-4, k
+    xt = np.random.default_rng(1).normal(size=(4, d))
+    for t in (900, 500, 100):
+        a, b = float(SCH.a[t]), float(SCH.b[t])
+        coeff = a * lam_ref / (a * a * lam_ref + b * b)
+        ref = mu_ref + (((xt - a * mu_ref) @ v_ref) * coeff) @ v_ref.T
+        got = wiener_x0((mu, jnp.asarray(V, jnp.float32), lam),
+                        jnp.asarray(xt, jnp.float32), a, b)
+        assert _rel(got, ref) < 1e-4, t
+
+
+def test_wiener_basis_leaves_out_padding_rows():
+    """Rows with ``+inf`` norms (capacity padding) do not enter the
+    mean or the covariance."""
+    from repro.core.dataset import make_store
+    from repro.core.denoisers import wiener_basis
+    x = _spread_data(200, 8, seed=3).astype(np.float32)
+    store = make_store(x, (8,), proxy_factor=1)
+    padded = make_store(np.concatenate([x, np.zeros((56, 8), np.float32)]),
+                        (8,), proxy_factor=1)
+    padded = padded._replace(
+        x_norms=padded.x_norms.at[200:].set(jnp.inf))
+    want, got = wiener_basis(store.rows, store.x_norms), \
+        wiener_basis(padded.rows, padded.x_norms)
+    np.testing.assert_allclose(got.mu, want.mu, rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(got.lam, want.lam, rtol=1e-4)
+
+
 def test_patch_denoiser_patch_schedule():
     store = cifar_like(64, seed=0)
     den = PatchDenoiser(store, SCH, patch_min=3, patch_max=11)

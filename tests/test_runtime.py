@@ -1,6 +1,7 @@
 """Fault-tolerant serving runtime (repro/launch/runtime.py): admission
 validation, deadlines at plan seams, retry/fallback, the degradation
 ladder, and the zero-compile guarantee on every warmed rung."""
+import math
 import os
 import subprocess
 import sys
@@ -193,6 +194,75 @@ def test_retries_exhausted_falls_back_to_gaussian(eng):
     assert t.status == "done" and t.degraded
     assert np.isfinite(t.images).all()
     assert r.counters["gauss_segments"] >= 1
+
+
+def test_gauss_program_takes_the_basis_as_an_argument():
+    """The Gaussian rung's program closes over no array of the store:
+    lowered for a 64-row store, its text holds no constant of D x r
+    elements or more (the schedule's 1,001-entry tables are smaller)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    e = ServeEngine("gmm", {"n": 64, "dim": 64}, num_steps=4, max_batch=2)
+    r = ServeRuntime(e)
+    d = rank = 64
+    text = r._gauss_program(2).lower(
+        jnp.zeros((2, d)), jnp.ones((r._gauss_nts,), jnp.int32),
+        jnp.int32(0), jnp.int32(1), *r._basis(0)).as_text()
+    sizes = [math.prod(int(v) for v in dims.split("x")[:-1])
+             for dims in re.findall(r"stablehlo.constant .*: tensor<([^>]*)>",
+                                    text)]
+    assert sizes and max(sizes) < d * rank, sorted(sizes)[-3:]
+
+
+def test_gauss_rung_follows_hot_swap_with_no_new_build():
+    """After a hot swap to a store of the same shapes with a shifted
+    mean, the Gaussian rung serves the new store's statistics through
+    the programs warmed for the first: nothing compiles.  The swap
+    builds the new epoch's basis in a ``runtime.wiener_basis`` span."""
+    import io
+    import logging
+
+    import jax
+
+    from repro.core.dataset import make_store
+    from repro.obs.trace import Tracer, set_tracer
+    e = ServeEngine("gmm", {"n": 128, "dim": 16}, num_steps=4, max_batch=2)
+    r = _fresh(e, max_retries=0)
+    shift = 0.5
+
+    def gauss_only(rid):
+        with injected(FaultConfig(seed=9, error_rate=1.0)):
+            t = r.submit(Request(rid, 2, seed=61))
+            r.run_until_idle()
+        assert t.status == "done" and t.degraded
+        return np.asarray(t.images)
+
+    before = gauss_only(0)
+    builds = e.engine._builds
+    shifted = make_store(e.store.X + shift, (16,), proxy_factor=1)
+    log = io.StringIO()
+    handler = logging.StreamHandler(log)
+    logging.getLogger("jax").addHandler(handler)
+    try:
+        with jax.log_compiles(True):
+            tr = Tracer()
+            set_tracer(tr)
+            try:
+                epoch = r.hot_swap(shifted)
+            finally:
+                set_tracer(None)
+            after = gauss_only(1)
+    finally:
+        logging.getLogger("jax").removeHandler(handler)
+    spans = [ev["tags"] for ev in tr.events()
+             if ev["name"] == "runtime.wiener_basis" and ev["kind"] == "begin"]
+    assert spans == [{"dim": 16, "rank": 16, "epoch": epoch}], spans
+    assert e.engine._builds == builds
+    assert "Compiling" not in log.getvalue(), log.getvalue()[:500]
+    assert r.counters["gauss_segments"] >= 2
+    assert abs(float(np.mean(after - before)) - shift) < 0.1 * shift
 
 
 def test_oom_splits_wave_and_halves_admission(eng):

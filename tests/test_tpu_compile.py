@@ -182,6 +182,53 @@ def test_16bit_store_stage_reads_the_store_in_place(one_chip, width, stage):
             < gathered + n * d * 2 // 2)
 
 
+# (N, D) of the stores whose Wiener basis the Gaussian rung builds
+BASIS_WIDTHS = {"cifar10": (50_000, 3072), "afhq64": (14_630, 12_288)}
+
+
+@pytest.mark.parametrize("width", list(BASIS_WIDTHS))
+def test_wiener_basis_compiles_for_v5e(one_chip, width):
+    """The Gaussian rung's basis program (blocked Gram matrix and
+    ``eigh``) for one chip: it makes no f32 array of the store's size
+    besides the rows themselves (no centred or flattened copy), and its
+    temporaries stay under eight ``[D, D]`` fp32 matrices."""
+    from repro.core.denoisers import WIENER_BLOCK_BYTES, _wiener_basis
+    n, d = BASIS_WIDTHS[width]
+    args = [jax.ShapeDtypeStruct((n, 1, d), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip)]
+    compiled = _wiener_basis.lower(
+        *args, rank=min(n, d), groups=1,
+        block=WIENER_BLOCK_BYTES // (4 * d)).compile()
+    store_sized = {dims for dims in _f32_arrays(compiled.as_text(), n * d)
+                   if math.prod(int(v) for v in dims.split(",")) >= n * d}
+    assert store_sized <= {f"{n},1,{d}", f"1,{n},1,{d}"}, store_sized
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * d * d * 4, mem.temp_size_in_bytes
+    print(f"{width}: temp {mem.temp_size_in_bytes} B, args "
+          f"{mem.argument_size_in_bytes} B, out {mem.output_size_in_bytes} B")
+
+
+def test_wiener_basis_on_sharded_rows_compiles_for_v5e_2x2(topo):
+    """The same basis program over rows sharded across the four chips
+    (CIFAR-10 width): each chip sums the Gram matrix of its own rows and
+    an all-reduce adds them; no chip gathers the store or holds a
+    store-sized f32 array besides its rows."""
+    from repro.core.denoisers import WIENER_BLOCK_BYTES, _wiener_basis
+    n, d = BASIS_WIDTHS["cifar10"]
+    rows = NamedSharding(Mesh(topo.devices[:4], ("data",)),
+                         PartitionSpec("data"))
+    compiled = _wiener_basis.lower(
+        jax.ShapeDtypeStruct((n, 1, d), jnp.float32, sharding=rows),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=rows),
+        rank=d, groups=4, block=WIENER_BLOCK_BYTES // (4 * d)).compile()
+    text = compiled.as_text()
+    assert "all-reduce" in text and "all-gather" not in text
+    n_loc = n // 4
+    assert _f32_arrays(text, n_loc * d) <= {
+        f"{n_loc},1,{d}", f"1,{n_loc},1,{d}"}, _f32_arrays(text, n_loc * d)
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * d * d * 4
+
+
 @pytest.mark.parametrize("path", ["fused", "staged", "indexed"])
 def test_sharded_step_compiles_for_v5e_2x2(topo, path):
     """The sharded engine's shard-local steps across the four chips of
